@@ -126,6 +126,8 @@ def run(m_train=3000, q=256, d=64, n_clients=30, iters=200,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import sys
     full = "--full" in sys.argv
     kw = dict(m_train=12000, q=2000, d=784, iters=350) if full else {}

@@ -36,5 +36,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run():
         print(",".join(str(x) for x in r))

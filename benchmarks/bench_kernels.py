@@ -47,5 +47,7 @@ def run(m=12000, d=784, q=2000, c=10, u=1200):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run():
         print(",".join(str(x) for x in r))

@@ -26,5 +26,7 @@ def run(n_clients=30, minibatch=400, q=2000, c=10, deltas=(0.05, 0.1, 0.2)):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run():
         print(",".join(str(x) for x in r))

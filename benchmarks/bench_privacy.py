@@ -24,5 +24,7 @@ def run(l=400, q=2000, deltas=(0.05, 0.1, 0.2, 0.5)):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run():
         print(",".join(str(x) for x in r))

@@ -1,12 +1,15 @@
 """Kill/resume smoke: SIGKILL a checkpointing run mid-flight, resume it,
 and require bit-equality with the uninterrupted control run.
 
-Driver mode (default) runs the control in-process, spawns this same file
-in ``--child`` mode (a block-structured run that checkpoints every block
-and sleeps between blocks to widen the kill window), SIGKILLs the child
-once at least two checkpoints are on disk, resumes from the latest one,
-and asserts the final model / wall-clock log / returned counts match the
-control exactly.  Exit code 0 = bit-identical; anything else fails CI.
+Parent mode (default) never imports JAX, so that only one process at a
+time holds the accelerator.  It runs three children of this same file,
+one after another: ``--role control`` (the uninterrupted run), ``--role
+child`` (a block-structured run that checkpoints every block and sleeps
+between blocks to widen the kill window, SIGKILLed once at least two
+checkpoints are on disk) and ``--role resume`` (resumes from the latest
+checkpoint).  Control and resume each write their final model /
+wall-clock log / returned counts to a JSON file, and the parent asserts
+they match exactly.  Exit code 0 = bit-identical; anything else fails CI.
 
     PYTHONPATH=src python benchmarks/resume_smoke.py --ckpt-dir /tmp/ck
 """
@@ -42,7 +45,7 @@ def build():
 
 
 def child(ckpt_dir: str) -> None:
-    """Checkpoint every block, sleeping in between so the driver can
+    """Checkpoint every block, sleeping in between so the parent can
     SIGKILL between (not during) block computations."""
     from repro.checkpoint import io as ckpt_io
     exp = build()
@@ -56,27 +59,54 @@ def child(ckpt_dir: str) -> None:
         time.sleep(0.5)
 
 
-def driver(ckpt_dir: str, out: str) -> int:
-    from repro.checkpoint import io as ckpt_io
+def finished_run(ckpt_dir: str, resume: bool, result: str) -> None:
+    """Run to the end (fresh, or resumed from `ckpt_dir`) and write the
+    final model and per-round log to `result` as JSON (floats round-trip
+    exactly, so the parent's comparison stays bit-for-bit)."""
+    if resume:
+        res = build().run(ITERATIONS, checkpoint_dir=ckpt_dir, resume=True)
+    else:
+        res = build().run(ITERATIONS)
+    with open(result, "w") as fh:
+        json.dump({"theta": np.asarray(res.theta).tolist(),
+                   "wall_clock": [h.wall_clock for h in res.history],
+                   "returned": [h.returned for h in res.history],
+                   "privacy_eps": res.privacy_eps}, fh)
+
+
+def _child_cmd(role: str, ckpt_dir: str, result: str = "") -> list:
+    cmd = [sys.executable, os.path.abspath(__file__), "--role", role,
+           "--ckpt-dir", ckpt_dir]
+    return cmd + (["--result", result] if result else [])
+
+
+def _checkpointed_rounds(ckpt_dir: str) -> int:
+    """Rounds covered by the newest ``ckpt_<rounds>.npz`` in `ckpt_dir`
+    (the runtime's checkpoint naming), read without importing JAX."""
+    rounds = [int(name[len("ckpt_"):-len(".npz")])
+              for name in os.listdir(ckpt_dir)
+              if name.startswith("ckpt_") and name.endswith(".npz")
+              and ".tmp" not in name]
+    return max(rounds, default=0)
+
+
+def parent(ckpt_dir: str, out: str) -> int:
     os.makedirs(ckpt_dir, exist_ok=True)
+    control_json = os.path.join(ckpt_dir, "control.json")
+    resumed_json = os.path.join(ckpt_dir, "resumed.json")
 
-    control = build().run(ITERATIONS)
+    subprocess.run(_child_cmd("control", ckpt_dir, control_json),
+                   check=True)
 
-    proc = subprocess.Popen(
-        [sys.executable, os.path.abspath(__file__),
-         "--child", "--ckpt-dir", ckpt_dir],
-        env=dict(os.environ))
+    proc = subprocess.Popen(_child_cmd("child", ckpt_dir))
     deadline = time.time() + 300
     killed_at = None
     try:
         while time.time() < deadline:
-            latest = ckpt_io.latest_checkpoint(ckpt_dir)
-            if latest is not None:
-                rounds = int(os.path.basename(latest)
-                             [len(ckpt_io.CKPT_PREFIX):-len(".npz")])
-                if rounds >= KILL_AFTER:
-                    killed_at = rounds
-                    break
+            rounds = _checkpointed_rounds(ckpt_dir)
+            if rounds >= KILL_AFTER:
+                killed_at = rounds
+                break
             if proc.poll() is not None:
                 print(f"FAIL: child exited early (rc={proc.returncode}) "
                       "before reaching the kill point", file=sys.stderr)
@@ -92,15 +122,17 @@ def driver(ckpt_dir: str, out: str) -> int:
         return 2
     assert killed_at < ITERATIONS, "child finished before the kill"
 
-    resumed = build().run(ITERATIONS, checkpoint_dir=ckpt_dir, resume=True)
+    subprocess.run(_child_cmd("resume", ckpt_dir, resumed_json), check=True)
+    with open(control_json) as fh:
+        control = json.load(fh)
+    with open(resumed_json) as fh:
+        resumed = json.load(fh)
 
-    theta_ok = bool(np.array_equal(np.asarray(control.theta),
-                                   np.asarray(resumed.theta)))
-    wall_ok = [h.wall_clock for h in control.history] \
-        == [h.wall_clock for h in resumed.history]
-    ret_ok = [h.returned for h in control.history] \
-        == [h.returned for h in resumed.history]
-    eps_ok = control.privacy_eps == resumed.privacy_eps
+    theta_ok = bool(np.array_equal(np.asarray(control["theta"]),
+                                   np.asarray(resumed["theta"])))
+    wall_ok = control["wall_clock"] == resumed["wall_clock"]
+    ret_ok = control["returned"] == resumed["returned"]
+    eps_ok = control["privacy_eps"] == resumed["privacy_eps"]
     ok = theta_ok and wall_ok and ret_ok and eps_ok
 
     report = {
@@ -122,16 +154,25 @@ def driver(ckpt_dir: str, out: str) -> int:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--child", action="store_true",
-                    help="run the killable checkpointing loop")
+    ap.add_argument("--role", default="parent",
+                    choices=("parent", "control", "child", "resume"),
+                    help="parent (default) runs the other three roles "
+                         "as children, one at a time")
     ap.add_argument("--ckpt-dir", required=True)
+    ap.add_argument("--result", default="",
+                    help="JSON result path (control/resume roles)")
     ap.add_argument("--out", default="",
-                    help="optional JSON report path (driver mode)")
+                    help="optional JSON report path (parent mode)")
     args = ap.parse_args()
-    if args.child:
+    if args.role == "parent":
+        return parent(args.ckpt_dir, args.out)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    if args.role == "child":
         child(args.ckpt_dir)
-        return 0
-    return driver(args.ckpt_dir, args.out)
+    else:
+        finished_run(args.ckpt_dir, args.role == "resume", args.result)
+    return 0
 
 
 if __name__ == "__main__":

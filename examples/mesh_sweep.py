@@ -19,6 +19,9 @@ import jax
 from repro.api import ExperimentSpec, build_experiment, grid_names
 from repro.config import FLConfig, TrainConfig
 from repro.core.delay_model import HETEROGENEITY_PROFILES
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 N, L, Q, C = 12, 32, 64, 5
 ITERS, REALIZATIONS = 30, 4

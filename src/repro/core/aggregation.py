@@ -21,7 +21,7 @@ from repro.kernels import ops
 
 def batched_client_gradients(x_stack, y_stack, theta, *, mask=None,
                              use_pallas: bool = False,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """All-client unnormalized gradients in one call.
 
     x_stack: (n, l, q), y_stack: (n, l, c), theta: (q, c) -> (n, q, c).
@@ -88,7 +88,7 @@ def fused_client_parity_tensors(sub_x, sub_y, mask, parity_x, parity_y, *,
 def fused_embed_client_gradients(x_raw, y_stack, omega, delta, theta, *,
                                  mask, parity_phi=None,
                                  use_pallas: bool = False,
-                                 interpret: bool = True):
+                                 interpret: bool | None = None):
     """All-client gradients straight from RAW features in one fused call.
 
     x_raw: (n, l, d) raw features, y_stack: (rows, l, c), mask: (rows, l)
@@ -139,7 +139,7 @@ def client_gradient(x, y, theta, *, use_pallas: bool = False):
 
 
 def coded_gradient(parity_x, parity_y, theta, pnr_c: float = 0.0,
-                   *, use_pallas: bool = False, interpret: bool = True):
+                   *, use_pallas: bool = False, interpret: bool | None = None):
     """g_C over the global parity set (eq. 28).
 
         g_C = 1/(1-pnr_C) * (1/u) * Xv^T (Xv theta - Yv)

@@ -62,7 +62,7 @@ def encode_local(key, x_hat, y, w, u: int, *, kind: str = "normal",
 def encode_local_batched(keys, x_stack, y_stack, w_stack, u: int, *,
                          kind: str = "normal",
                          use_pallas: bool = False,
-                         interpret: bool = True) -> LocalParity:
+                         interpret: bool | None = None) -> LocalParity:
     """All-clients parity encode in one batched call.
 
     keys: (n,) stacked PRNG keys (one per client, identical to what a

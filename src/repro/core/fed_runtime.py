@@ -125,13 +125,13 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.checkpoint import io as ckpt_io
 from repro.config import ExperimentSpec
 from repro.core import aggregation, rff as rff_mod, schemes
 from repro.kernels import ops as kernel_ops
+from repro.kernels.backend import resolve_interpret
 from repro.core.delay_model import (mec_network, packet_bits,
                                     sample_round_times, scale_tau)
 from repro.core.run_state import RunState, pack_state, unpack_state
@@ -301,13 +301,13 @@ def _make_grad_sum(static: dict):
     def shard(gx, gy, gmask, ret, theta):
         return jax.lax.psum(local(gx, gy, gmask, ret, theta), CLIENT_AXIS)
 
-    # check_rep=False: pallas_call has no replication rule; correctness is
+    # check_vma=False: pallas_call has no replication rule; correctness is
     # covered by the psum (out is explicitly replicated by the reduction).
-    return shard_map(
+    return jax.shard_map(
         shard, mesh=mesh,
         in_specs=(P(CLIENT_AXIS), P(CLIENT_AXIS), P(CLIENT_AXIS),
                   P(CLIENT_AXIS), P()),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
 
 
 def build_step(static: dict):
@@ -611,12 +611,12 @@ class Experiment:
         fl_cfg = spec.resolved_fl()      # delay-profile knobs applied
         self.engine = spec.engine
         # "pallas" routes the batched engine's gradient calls through the
-        # fused Pallas kernels (interpret mode off-TPU so CI stays green on
-        # CPU); "xla" keeps the plain-jnp vmapped path.  The legacy oracle
-        # engine always uses the jnp path.
+        # fused Pallas kernels (compiled on a TPU, interpret mode
+        # elsewhere); "xla" keeps the plain-jnp vmapped path.  The legacy
+        # oracle engine always uses the jnp path.
         self.kernel_backend = spec.kernel_backend
         self.alloc_backend = spec.alloc_backend
-        self._interpret = jax.default_backend() != "tpu"
+        self._interpret = resolve_interpret()
         self.mesh = self._resolve_mesh(spec.mesh if mesh is None else mesh)
         self.fused_coded = spec.fused_coded
         self.secure_aggregation = spec.secure_aggregation
@@ -890,7 +890,8 @@ class Experiment:
         ``scan/execute``.  Disabled spans delegate straight through — no
         sync, no clock read; enabled spans block on the output inside the
         span (same values, the device sync is just forced before the
-        clock stops)."""
+        clock stops).  ``.lower`` is the jitted scan's, so the compiled
+        round program can be inspected."""
         state = {"warm": False}
 
         def call(*args):
@@ -903,6 +904,7 @@ class Experiment:
             state["warm"] = True
             return out
 
+        call.lower = fn.lower
         return call
 
     def _get_scan(self, collect_theta: bool):

@@ -437,11 +437,10 @@ def vectorized_optimal_loads(nodes: Sequence[NodeDelayParams], t: float,
     through the flattened per-direction transmission grid; the scalar
     path is the oracle the property tests compare against.
     """
-    from jax.experimental import enable_x64
     prm = stack_node_params(nodes)
     v_cap = _tail_v_cap(float(prm["p_down"].max()))
     h, comm = _transmission_grids(prm)
-    with enable_x64():
+    with jax.enable_x64(True):
         loads, rets = jax.jit(_vec_optimal_loads,
                               static_argnames=("v_cap", "n_golden"))(
             jnp.asarray(prm["mu"]), jnp.asarray(prm["alpha"]),
@@ -473,7 +472,6 @@ def two_step_allocate_vectorized(clients: Sequence[NodeDelayParams],
     documents that contract — iteration counts are fixed and exceed it).
     Float64 throughout via a local x64 scope.
     """
-    from jax.experimental import enable_x64
     nodes = list(clients)
     caps = [float(cp) for cp in client_caps]
     target = float(m)
@@ -487,7 +485,7 @@ def two_step_allocate_vectorized(clients: Sequence[NodeDelayParams],
     prm = stack_node_params(nodes)
     v_cap = _tail_v_cap(float(prm["p_down"].max()))
     h, comm = _transmission_grids(prm)
-    with enable_x64():
+    with jax.enable_x64(True):
         t_star, loads, rets = _vec_two_step(
             jnp.asarray(prm["mu"]), jnp.asarray(prm["alpha"]),
             jnp.asarray(prm["tau_down"]), jnp.asarray(h), jnp.asarray(comm),

@@ -278,10 +278,13 @@ def _stack_blocks(prm: dict, caps: np.ndarray, block_size: int):
 
     Dead nodes (cap 0, erasure 0) contribute an exact +0.0 to the
     sequential total, so trailing padding never changes a single bit of
-    any partition's result.
+    any partition's result.  There are always at least two blocks: XLA
+    folds a one-trip scan into straight-line code that fuses (and so
+    rounds) differently from the loop body, which made a lone block
+    differ from a multi-block partition by an ulp.
     """
     n = caps.shape[0]
-    n_blocks = -(-n // block_size)
+    n_blocks = max(2, -(-n // block_size))
     pad = n_blocks * block_size - n
     def padded(arr, fill):
         return np.concatenate(
@@ -318,13 +321,13 @@ def two_step_allocate_chunked(clients=None, client_caps=None,
 
     Bit-equality: results are identical for EVERY ``block_size``
     (internally rounded up to a `SUM_STRIPE` multiple so the total
-    fold's stripes stay block-aligned), including the single-block call
-    that is the dense one-shot path of this tier; agreement with
+    fold's stripes stay block-aligned, and padded to at least two
+    blocks — see `_stack_blocks`), including the widest call that is
+    the dense one-shot path of this tier; agreement with
     `two_step_allocate_vectorized` holds to the solver tolerance (see
     module docstring for why the dense `jnp.sum` association cannot be
     chunked bit-exactly).
     """
-    from jax.experimental import enable_x64
     if prm is None:
         prm = load_allocation.stack_node_params(list(clients))
     n = prm["mu"].shape[0]
@@ -355,7 +358,7 @@ def two_step_allocate_chunked(clients=None, client_caps=None,
     vu_cap = load_allocation._geo_tail_cap(float(prm["p_up"].max()))
     blocks = _stack_blocks(prm, caps, block_size)
     static = dict(v_cap=v_cap, sym=sym, vd_cap=vd_cap, vu_cap=vu_cap)
-    with enable_x64():
+    with jax.enable_x64(True):
         args = tuple(jnp.asarray(b) for b in blocks)
 
         def total(t: float) -> float:

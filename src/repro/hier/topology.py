@@ -67,6 +67,7 @@ from repro.core import schemes as schemes_registry
 from repro.core.delay_model import packet_bits, sample_round_times_stacked
 from repro.core.run_state import RunState, pack_state, unpack_state
 from repro.hier import population, sampling
+from repro.kernels.backend import resolve_interpret
 from repro.obs import spans as obs_spans
 
 #: default client block width of the streamed parity encode (encode
@@ -186,7 +187,7 @@ class HierExperiment:
         self.steps_per_epoch = spec.steps_per_epoch
         self.checkpoint_every = spec.checkpoint_every
         self._use_pallas = spec.kernel_backend == "pallas"
-        self._interpret = jax.default_backend() != "tpu"
+        self._interpret = resolve_interpret()
         # --- data plumbing: dense slices or a streaming block callable
         if data_fn is not None:
             if x_stack is not None or y_stack is not None:

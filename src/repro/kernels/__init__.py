@@ -10,9 +10,11 @@
 
 Each kernel has a pure-jnp oracle in ref.py; ops.py holds jit'd wrappers
 with padding + fallback (plus the vmap-compatible batched entry points
-linreg_grad_batched / rff_embed_batched).  Kernels target TPU v5e
-BlockSpec/VMEM tiling and are validated on CPU in interpret mode
-(tests/test_kernels.py, marked `kernels`).
+linreg_grad_batched / rff_embed_batched).  backend.py decides where they
+run: Mosaic-compiled on a TPU, the Pallas interpreter elsewhere.  Kernels
+target TPU v5e BlockSpec/VMEM tiling, are validated on CPU in interpret
+mode (tests/test_kernels.py, marked `kernels`) and compiled for a
+described v5e chip (tests/test_tpu_compile.py).
 """
 from repro.kernels import ops, ref
 

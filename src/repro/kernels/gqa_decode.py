@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -65,7 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
 @functools.partial(jax.jit,
                    static_argnames=("bt", "window", "interpret"))
 def gqa_decode(q, k, v, k_pos, q_pos, *, bt: int = 512, window: int = 0,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """q: (B, H, hd); k: (B, T, K, hd); v: (B, T, K, hd_v);
     k_pos: (T,) int32 slot positions; q_pos: () int32.
     Returns (B, H, hd_v).  Requires T % bt == 0 and H % K == 0."""
@@ -94,5 +96,5 @@ def gqa_decode(q, k, v, k_pos, q_pos, *, bt: int = 512, window: int = 0,
             pltpu.VMEM((K, G), jnp.float32),
             pltpu.VMEM((K, G, hd_v), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, kpos2, qpos2)

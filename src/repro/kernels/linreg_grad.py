@@ -30,35 +30,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-# Per-core VMEM is ~16 MiB on current TPUs; leave headroom for double
-# buffering of the streamed input blocks.
+from repro.kernels.backend import resolve_interpret
+
+# A v5e core has 128 MiB of VMEM, but Mosaic gives one kernel a scoped
+# 16 MiB of it unless the call raises `vmem_limit_bytes`; these kernels
+# keep that default, so their working set must fit 16 MiB.
 _VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+
+#: lane width a (rows, 1) VMEM column is padded out to
+_LANE = 128
 
 
 def _check_c_fits_vmem(q: int, c: int, bm: int, bq: int, dtype) -> None:
-    """Validate that the label width `c` leaves the kernel's resident VMEM
-    working set inside the budget.
+    """Validate that the label width `c` leaves the kernel's VMEM working
+    set inside the budget.
 
-    theta (q, c), one labels row-block (bm, c), the residual scratch (bm, c)
-    and the output tile (bq, c) are all resident per grid step, so a large c
-    (or q) blows VMEM with an opaque Mosaic/Pallas shape assert.  Raise a
-    clear, actionable error instead.
+    Pallas double-buffers every block whose index moves over the grid —
+    the full-q X row block (bm, q), the labels (bm, c), the masked
+    variant's weight column (bm, 1) padded to a lane, the X^T side tile
+    (bm, bq) and the output tile (bq, c) — and holds grid-invariant
+    theta (q, c) and the residual scratch (bm, c) once.  This matches
+    the scoped VMEM the TPU compiler reports for these kernels to within
+    a few tiles, so a large c (or q) gets a clear, actionable error here
+    instead of an opaque compiler one.
     """
     itemsize = jnp.dtype(dtype).itemsize
-    resident = (bm * q          # full-q X row block (residual operand)
-                + q * c         # theta, resident across the whole grid
-                + bm * c        # Y row block
-                + bm * c        # residual scratch
-                + bm * bq       # X^T side tile
-                + bq * c)       # output tile
-    nbytes = resident * itemsize
+    streamed = bm * q + bm * c + bm * _LANE + bm * bq + bq * c
+    resident = q * c + bm * c
+    nbytes = (2 * streamed + resident) * itemsize
     if nbytes > _VMEM_BUDGET_BYTES:
         raise ValueError(
             f"linreg_grad: label width c={c} with q={q}, bm={bm}, bq={bq} "
             f"({jnp.dtype(dtype).name}) needs ~{nbytes / 2**20:.1f} MiB of "
-            f"resident VMEM (theta + label/residual/output tiles), over the "
-            f"~{_VMEM_BUDGET_BYTES / 2**20:.0f} MiB per-core budget. Split "
-            "the label columns into <=128-wide chunks or shrink bm/bq.")
+            f"VMEM (double-buffered X/label/output tiles + theta), over the "
+            f"{_VMEM_BUDGET_BYTES / 2**20:.0f} MiB scoped kernel budget. "
+            "Split the label columns into <=128-wide chunks or shrink "
+            "bm/bq.")
 
 
 def _kernel(xfull_ref, theta_ref, y_ref, xblk_ref, o_ref, r_ref):
@@ -81,7 +88,7 @@ def _kernel(xfull_ref, theta_ref, y_ref, xblk_ref, o_ref, r_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bq", "interpret"))
 def linreg_grad(x, theta, y, *, bm: int = 128, bq: int = 128,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """g = X^T (X theta - Y): (m, q), (q, c), (m, c) -> (q, c)."""
     m, q = x.shape
     q2, c = theta.shape
@@ -100,7 +107,7 @@ def linreg_grad(x, theta, y, *, bm: int = 128, bq: int = 128,
         out_specs=pl.BlockSpec((bq, c), lambda i, j: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((q, c), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, c), x.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, theta, y, x)
 
 
@@ -114,7 +121,7 @@ def _masked_kernel(xfull_ref, theta_ref, y_ref, mask_ref, xblk_ref, o_ref,
         r = (jnp.dot(xfull_ref[0], theta_ref[...],
                      preferred_element_type=r_ref.dtype)
              - y_ref[0])
-        r_ref[...] = r * mask_ref[0][:, None].astype(r_ref.dtype)
+        r_ref[...] = r * mask_ref[0].astype(r_ref.dtype)     # (bm, 1)
 
     @pl.when(i == 0)
     def _init():
@@ -126,7 +133,7 @@ def _masked_kernel(xfull_ref, theta_ref, y_ref, mask_ref, xblk_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("bm", "bq", "interpret"))
 def linreg_grad_masked(x, theta, y, mask, *, bm: int = 128, bq: int = 128,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Per-client masked gradients:  g_j = X_j^T diag(mask_j) (X_j theta - Y_j).
 
     x: (n, l, q), theta: (q, c), y: (n, l, c), mask: (n, l) -> (n, q, c).
@@ -149,11 +156,11 @@ def linreg_grad_masked(x, theta, y, mask, *, bm: int = 128, bq: int = 128,
             pl.BlockSpec((1, bm, q), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((q, c), lambda b, i, j: (0, 0)),
             pl.BlockSpec((1, bm, c), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bm), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, bm, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bm, bq), lambda b, i, j: (b, i, j)),
         ],
         out_specs=pl.BlockSpec((1, bq, c), lambda b, i, j: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((n, q, c), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, c), x.dtype)],
-        interpret=interpret,
-    )(x, theta, y, mask, x)
+        interpret=resolve_interpret(interpret),
+    )(x, theta, y, mask.reshape(n, l, 1), x)

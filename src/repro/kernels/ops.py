@@ -1,10 +1,11 @@
 """jit'd public wrappers around the Pallas kernels with automatic padding
 and a pure-jnp fallback.
 
-`use_pallas=True` runs the Pallas kernels (interpret mode on CPU; compiled
-on a real TPU where `interpret` should be set False by the caller).  The
-default entry points pad inputs up to block multiples, run the kernel, and
-slice the result back, so arbitrary shapes are accepted.
+`use_pallas=True` runs the Pallas kernels: compiled on a TPU and in
+interpret mode elsewhere, unless the caller names `interpret`
+(`repro.kernels.backend.resolve_interpret`).  The default entry points
+pad inputs up to block multiples, run the kernel, and slice the result
+back, so arbitrary shapes are accepted.
 """
 from __future__ import annotations
 
@@ -12,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.linreg_grad import linreg_grad as _linreg_grad_kernel
 from repro.kernels.linreg_grad import \
     linreg_grad_masked as _linreg_grad_masked_kernel
@@ -42,7 +44,8 @@ def _pad_to(x, mults):
     return jnp.pad(x, pads)
 
 
-def _clamp_block(block: int, dim: int, interpret: bool, mult: int = 8) -> int:
+def _clamp_block(block: int, dim: int, interpret: bool | None,
+                 mult: int = 8) -> int:
     """Shrink a block size down to the (mult-rounded) dim — interpret only.
 
     The federated runtime's fused client+parity tensor often has a point
@@ -52,14 +55,14 @@ def _clamp_block(block: int, dim: int, interpret: bool, mult: int = 8) -> int:
     stay untouched: Mosaic requires 128-multiple lane dims there and the
     hardware pads implicitly anyway.
     """
-    if not interpret:
+    if not resolve_interpret(interpret):
         return block
     return max(mult, min(block, -(-dim // mult) * mult))
 
 
 def rff_embed(x, omega, delta, *, use_pallas: bool = False,
               bm: int = 128, bq: int = 128, bk: int = 128,
-              interpret: bool = True):
+              interpret: bool | None = None):
     if not use_pallas:
         return ref.rff_embed(x, omega, delta)
     m, d = x.shape
@@ -74,7 +77,7 @@ def rff_embed(x, omega, delta, *, use_pallas: bool = False,
 
 def rff_embed_batched(x_stack, omega, delta, *, use_pallas: bool = False,
                       bm: int = 128, bq: int = 128, bk: int = 128,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """vmap-compatible RFF embedding over a client axis.
 
     x_stack: (n, l, d), omega: (d, q), delta: (q,) -> (n, l, q).  The jnp
@@ -93,7 +96,7 @@ def rff_embed_batched(x_stack, omega, delta, *, use_pallas: bool = False,
 
 
 def linreg_grad(x, theta, y, *, use_pallas: bool = False,
-                bm: int = 128, bq: int = 128, interpret: bool = True):
+                bm: int = 128, bq: int = 128, interpret: bool | None = None):
     if not use_pallas:
         return ref.linreg_grad(x, theta, y)
     m, q = x.shape
@@ -107,7 +110,7 @@ def linreg_grad(x, theta, y, *, use_pallas: bool = False,
 
 def linreg_grad_masked(x_stack, theta, y_stack, mask, *,
                        use_pallas: bool = False, bm: int = 128, bq: int = 128,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """Per-client row-masked gradients over a dense padded client axis.
 
     x_stack: (n, l, q), theta: (q, c), y_stack: (n, l, c), mask: (n, l)
@@ -142,7 +145,7 @@ def linreg_grad_masked(x_stack, theta, y_stack, mask, *,
 def rff_linreg_grad_masked(x_raw, omega, delta, theta, y_stack, mask, *,
                            parity_phi=None, use_pallas: bool = False,
                            bm: int = 128, bq: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """Fused RFF-embed -> per-client masked gradients from RAW features.
 
     x_raw: (n, l, d) raw client features, omega: (d, q), delta: (q,),
@@ -198,7 +201,7 @@ def rff_linreg_grad_masked(x_raw, omega, delta, theta, y_stack, mask, *,
 
 
 def linreg_grad_batched(x_stack, theta, y_stack, *, use_pallas: bool = False,
-                        bm: int = 128, bq: int = 128, interpret: bool = True):
+                        bm: int = 128, bq: int = 128, interpret: bool | None = None):
     """Per-client gradients over a dense client axis.
 
     x_stack: (n, l, q), theta: (q, c), y_stack: (n, l, c) -> (n, q, c).
@@ -216,7 +219,7 @@ def linreg_grad_batched(x_stack, theta, y_stack, *, use_pallas: bool = False,
 
 def parity_encode(g, w, x, *, use_pallas: bool = False,
                   bu: int = 128, bq: int = 128, bl: int = 128,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     if not use_pallas:
         return ref.parity_encode(g, w, x)
     u, l = g.shape
@@ -232,7 +235,7 @@ def parity_encode(g, w, x, *, use_pallas: bool = False,
 def parity_encode_batched(g_stack, w_stack, x_stack, *,
                           use_pallas: bool = False, bu: int = 128,
                           bq: int = 128, bl: int = 128,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """All-clients parity encode over a dense client axis.
 
     g_stack: (n, u, l), w_stack: (n, l), x_stack: (n, l, q) -> (n, u, q)
@@ -256,7 +259,7 @@ def parity_encode_batched(g_stack, w_stack, x_stack, *,
 
 def gqa_decode(q, k, v, k_pos, q_pos, *, window: int = 0,
                use_pallas: bool = False, bt: int = 512,
-               interpret: bool = True):
+               interpret: bool | None = None):
     if not use_pallas:
         return ref.gqa_decode(q, k, v, k_pos, q_pos, window)
     T = k.shape[1]

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 
 def _kernel(g_ref, w_ref, x_ref, o_ref, *, nk: int):
     k = pl.program_id(2)
@@ -37,7 +39,7 @@ def _kernel(g_ref, w_ref, x_ref, o_ref, *, nk: int):
 
 @functools.partial(jax.jit, static_argnames=("bu", "bq", "bl", "interpret"))
 def parity_encode(g, w, x, *, bu: int = 128, bq: int = 128, bl: int = 128,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """(u, l), (l,), (l, q) -> (u, q).  Requires block divisibility."""
     u, l = g.shape
     l2, q = x.shape
@@ -55,7 +57,7 @@ def parity_encode(g, w, x, *, bu: int = 128, bq: int = 128, bl: int = 128,
         ],
         out_specs=pl.BlockSpec((bu, bq), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((u, q), g.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(g, w2, x)
 
 
@@ -73,7 +75,7 @@ def _batched_kernel(g_ref, w_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bu", "bq", "bl", "interpret"))
 def parity_encode_batched(g, w, x, *, bu: int = 128, bq: int = 128,
-                          bl: int = 128, interpret: bool = True):
+                          bl: int = 128, interpret: bool | None = None):
     """All-clients parity encode: (n, u, l), (n, l), (n, l, q) -> (n, u, q).
 
     Grid (n, U/bu, Q/bq, L/bl): the client axis is outermost, so the whole
@@ -95,5 +97,5 @@ def parity_encode_batched(g, w, x, *, bu: int = 128, bq: int = 128,
         ],
         out_specs=pl.BlockSpec((1, bu, bq), lambda b, i, j, k: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((n, u, q), g.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(g, w3, x)

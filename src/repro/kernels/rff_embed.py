@@ -17,6 +17,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+from repro.kernels.ref import embed_precision
+
 
 def _kernel(x_ref, omega_ref, delta_ref, o_ref, *, nk: int, q_true: int):
     k = pl.program_id(2)
@@ -26,6 +29,7 @@ def _kernel(x_ref, omega_ref, delta_ref, o_ref, *, nk: int, q_true: int):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     o_ref[...] += jnp.dot(x_ref[...], omega_ref[...],
+                          precision=embed_precision(x_ref.dtype),
                           preferred_element_type=o_ref.dtype)
 
     @pl.when(k == nk - 1)
@@ -37,7 +41,7 @@ def _kernel(x_ref, omega_ref, delta_ref, o_ref, *, nk: int, q_true: int):
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bq", "bk", "interpret", "q_true"))
 def rff_embed(x, omega, delta, *, bm: int = 128, bq: int = 128, bk: int = 128,
-              interpret: bool = True, q_true: int | None = None):
+              interpret: bool | None = None, q_true: int | None = None):
     """x: (m, d), omega: (d, q), delta: (q,) -> (m, q).  Requires divisibility.
 
     q_true: the unpadded feature count used in the sqrt(2/q) scale (defaults
@@ -65,5 +69,5 @@ def rff_embed(x, omega, delta, *, bm: int = 128, bq: int = 128, bk: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bq), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, q), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, omega, delta2)

@@ -41,31 +41,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.linreg_grad import _VMEM_BUDGET_BYTES
+from repro.kernels.backend import resolve_interpret
+from repro.kernels.linreg_grad import _LANE, _VMEM_BUDGET_BYTES
+from repro.kernels.ref import embed_precision
 
 _ACC = jnp.float32
 
 
 def _check_fused_vmem(d: int, q: int, c: int, bm: int, bq: int,
                       in_dtype) -> None:
-    """Clear error when the resident working set cannot fit VMEM.
+    """Clear error when the VMEM working set cannot fit the budget.
 
-    Omega (d, q) and theta (q, c) are resident across the whole grid; the
-    phi row-block scratch (bm, q) and residual (bm, c) are f32; the raw
-    row block (bm, d), labels (bm, c), parity row block (bm, q) and the
-    (bq, c) output tile stream per step.
+    Blocks whose index moves over the grid are double-buffered: the raw
+    row block (bm, d), labels (bm, c), the weight column (bm, 1) padded
+    to a lane, the parity row block (bm, q) and the f32 (bq, c) output
+    tile.  Grid-invariant Omega (d, q), delta (padded to 8 sublanes) and
+    theta (q, c) are held once, as are the f32 phi (bm, q) and residual
+    (bm, c) scratch.  With f32 inputs the embed matmul runs at full f32
+    (`ref.embed_precision`), and Mosaic's scratch for splitting its
+    operands into bf16 parts grows like four f32 copies of the (bm, d)
+    row block.  Checked against the TPU compiler for v5e at bm in
+    {128, 256, 512} and q in {512, ..., 4096}: the estimate never passes
+    a shape the compiler refuses, and at bm=128, q=2048 (the paper's
+    round) it refuses exactly what the compiler refuses.
     """
     in_size = jnp.dtype(in_dtype).itemsize
     acc_size = jnp.dtype(_ACC).itemsize
-    nbytes = ((d * q + q * c + bm * d + bm * c + bm * q) * in_size
-              + (bm * q + bm * c + bq * c) * acc_size)
+    streamed_in = bm * d + bm * c + bm * _LANE + bm * q
+    split = 4 * bm * d if embed_precision(in_dtype) is not None else 0
+    nbytes = ((2 * streamed_in + d * q + 8 * q + q * c) * in_size
+              + (2 * bq * c + bm * q + bm * c + split) * acc_size)
     if nbytes > _VMEM_BUDGET_BYTES:
         raise ValueError(
-            f"rff_linreg_grad: resident working set for d={d}, q={q}, "
+            f"rff_linreg_grad: working set for d={d}, q={q}, "
             f"c={c}, bm={bm}, bq={bq} ({jnp.dtype(in_dtype).name} inputs) "
             f"needs ~{nbytes / 2**20:.1f} MiB of VMEM (Omega + theta + phi "
-            f"scratch), over the ~{_VMEM_BUDGET_BYTES / 2**20:.0f} MiB "
-            "per-core budget. Shrink q/d or fall back to the two-pass "
+            f"scratch + double-buffered row blocks), over the "
+            f"{_VMEM_BUDGET_BYTES / 2**20:.0f} MiB scoped kernel budget. "
+            "Shrink q/d or fall back to the two-pass "
             "rff_embed + linreg_grad_masked path.")
 
 
@@ -83,6 +96,7 @@ def _kernel(x_ref, omega_ref, delta_ref, theta_ref, y_ref, mask_ref,
         @pl.when(b < n_real)
         def _embed():
             acc = jnp.dot(x_ref[0], omega_ref[...],
+                          precision=embed_precision(x_ref.dtype),
                           preferred_element_type=_ACC)
             scale = jnp.array(math.sqrt(2.0 / q_true), _ACC)
             phi_ref[...] = scale * jnp.cos(acc + delta_ref[...].astype(_ACC))
@@ -94,7 +108,7 @@ def _kernel(x_ref, omega_ref, delta_ref, theta_ref, y_ref, mask_ref,
         r = (jnp.dot(phi_ref[...], theta_ref[...].astype(_ACC),
                      preferred_element_type=_ACC)
              - y_ref[0].astype(_ACC))
-        r_ref[...] = r * mask_ref[0][:, None].astype(_ACC)
+        r_ref[...] = r * mask_ref[0].astype(_ACC)             # (bm, 1)
 
     @pl.when(i == 0)
     def _init():
@@ -109,7 +123,7 @@ def _kernel(x_ref, omega_ref, delta_ref, theta_ref, y_ref, mask_ref,
                                              "n_real", "q_true"))
 def rff_linreg_grad_masked(x_raw, omega, delta, theta, y, mask, pphi, *,
                            n_real: int, bm: int = 128, bq: int = 128,
-                           interpret: bool = True,
+                           interpret: bool | None = None,
                            q_true: int | None = None):
     """Fused embed->gradient over a dense padded client axis.
 
@@ -153,12 +167,12 @@ def rff_linreg_grad_masked(x_raw, omega, delta, theta, y, mask, pphi, *,
             pl.BlockSpec((1, q), lambda b, i, j: (0, 0)),          # delta
             pl.BlockSpec((q, c), lambda b, i, j: (0, 0)),          # theta
             pl.BlockSpec((1, bm, c), lambda b, i, j: (b, i, 0)),   # labels
-            pl.BlockSpec((1, bm), lambda b, i, j: (b, i)),         # weights
+            pl.BlockSpec((1, bm, 1), lambda b, i, j: (b, i, 0)),   # weights
             pl.BlockSpec((1, bm, q), lambda b, i, j: (0, i, 0)),   # parity phi
         ],
         out_specs=pl.BlockSpec((1, bq, c), lambda b, i, j: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, q, c), _ACC),
         scratch_shapes=[pltpu.VMEM((bm, q), _ACC),
                         pltpu.VMEM((bm, c), _ACC)],
-        interpret=interpret,
-    )(x_raw, omega, delta2, theta, y, mask, pphi)
+        interpret=resolve_interpret(interpret),
+    )(x_raw, omega, delta2, theta, y, mask.reshape(rows, L, 1), pphi)

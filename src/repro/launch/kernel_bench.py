@@ -12,8 +12,9 @@ same shapes, i.e. the measured payoff of never materializing the
 What is timed is the jit'd path of the selected ``kernel_backend``:
 ``"xla"`` (the CI default) times the plain-jnp reference compositions —
 Pallas interpret-mode wall time on CPU measures the interpreter, not the
-TPU target, so CI gates regressions on the XLA path and TPU runs pass
-``kernel_backend="pallas"`` with ``interpret=False`` for device numbers.
+TPU target, so CI gates regressions on the XLA path.  ``interpret``
+defaults to the platform (`repro.kernels.backend.resolve_interpret`):
+``kernel_backend="pallas"`` times the compiled kernels on a TPU.
 
 CI gate: `compare_kernels(fresh, committed, threshold)` flags any kernel
 whose us_per_call regressed past ``threshold`` x the committed artifact's
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.backend import resolve_interpret
 
 #: kernel names every ``kernels`` section must time
 KERNEL_NAMES = ("rff_embed", "linreg_grad_masked", "parity_encode_batched",
@@ -66,7 +68,7 @@ def run_kernel_bench(n_clients: int = 12, l: int = 128, d: int = 64,
                      q: int = 512, c: int = 8, u: int = 128,
                      iters: int = 10, seed: int = 0,
                      kernel_backend: str = "xla",
-                     interpret: bool = True) -> dict:
+                     interpret: bool | None = None) -> dict:
     """Time the round path's kernels at one shape; return the section dict.
 
     Shapes mirror the runtime's layouts: embedding flattens the client
@@ -77,6 +79,7 @@ def run_kernel_bench(n_clients: int = 12, l: int = 128, d: int = 64,
     if kernel_backend not in ("xla", "pallas"):
         raise ValueError(f"unknown kernel_backend {kernel_backend!r}")
     use_pallas = kernel_backend == "pallas"
+    interpret = resolve_interpret(interpret)
     rng = np.random.default_rng(seed)
     x_raw = jnp.asarray(rng.normal(size=(n_clients, l, d)), jnp.float32)
     omega = jnp.asarray(rng.normal(size=(d, q)) / 5.0, jnp.float32)

@@ -336,8 +336,8 @@ def test_rff_linreg_grad_fused_parity_row():
                                       parity_phi=parity_phi)
     assert got.shape == (n + 1, q, c)
     # single-block shapes: the padded contraction contributes exact zeros,
-    # so pallas and jnp agree bit for bit
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # so pallas and jnp differ only in XLA's reduction order
+    _assert_f32_close(got, want)
     # the parity row must equal the plain masked gradient on parity_phi
     par = ref.linreg_grad_masked(parity_phi, theta, y[n], mask[n])
     np.testing.assert_allclose(np.asarray(got[n]), np.asarray(par),
@@ -410,7 +410,11 @@ def test_rff_linreg_grad_fused_rejects_bad_args():
 # `_clamp_block` regression (wrong scale, garbage in the pad, off-by-one
 # slicing) breaks exact equality loudly.  One past the block multiple the
 # contraction legitimately splits into two accumulation steps, so those
-# cases assert tight allclose instead.
+# cases assert tight allclose instead.  The masked gradient contracts
+# twice (over q, then over the rows), and XLA associates the reference's
+# two dots differently from the kernel's blocked ones, so its in-block
+# cases hold to a few f32 ulp of the output's scale rather than bit for
+# bit; garbage in the pad or a wrong mask is still orders larger.
 
 _EDGE = (127, 128, 129)   # around the 128-lane block
 
@@ -421,6 +425,14 @@ def _assert_edge(got, want, exact):
         np.testing.assert_array_equal(got, want)
     else:
         np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+
+def _assert_f32_close(got, want):
+    """Agreement to ~8 f32 ulp of the output's largest magnitude."""
+    got, want = np.asarray(got), np.asarray(want)
+    scale = max(float(np.abs(want).max()), 1.0)
+    np.testing.assert_allclose(got / scale, want / scale, rtol=0,
+                               atol=8 * np.finfo(np.float32).eps)
 
 
 @pytest.mark.parametrize("d", _EDGE)
@@ -446,7 +458,10 @@ def test_linreg_grad_masked_padding_edges(l, q):
     got = ops.linreg_grad_masked(x, theta, y, mask, use_pallas=True)
     want = jnp.stack([ref.linreg_grad_masked(x[j], theta, y[j], mask[j])
                       for j in range(n)])
-    _assert_edge(got, want, exact=(l <= 128))
+    if l <= 128:
+        _assert_f32_close(got, want)
+    else:
+        _assert_edge(got, want, exact=False)
 
 
 @pytest.mark.parametrize("l", _EDGE)

@@ -95,7 +95,7 @@ def test_mesh_run_multi_matches_single_device(scheme, ndev):
 
 
 def test_mesh_pallas_backend_matches_xla():
-    """Pallas kernels inside shard_map (check_rep=False) == XLA mesh path."""
+    """Pallas kernels inside shard_map (check_vma=False) == XLA mesh path."""
     _skip_unless(2)
     xs, ys = _data()
     res_x = _sim(xs, ys, "coded", mesh=2).run(10)
