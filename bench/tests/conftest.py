@@ -1,0 +1,10 @@
+"""The benchmark's modules import each other by bare name, as
+``bench/run.py`` arranges; tests see them the same way."""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for path in (os.path.join(ROOT, "src"), BENCH):
+    if path not in sys.path:
+        sys.path.insert(0, path)
