@@ -521,6 +521,18 @@ def _pad_rows(arr: jnp.ndarray, rows: int) -> jnp.ndarray:
     return jnp.pad(arr, ((0, extra),) + ((0, 0),) * (arr.ndim - 1))
 
 
+def _round_rows(consts: dict) -> int:
+    """Rows the compiled round reads each round: the point rows of the
+    step's gradient tensor (with the fused coded path, n + 1 row blocks
+    of L points, the parity set the last; mesh padding included), plus
+    the parity rows where they ride as separate consts."""
+    gx = consts["gx"]
+    rows = int(gx.shape[0]) * int(gx.shape[1])
+    if "par_x" in consts:
+        rows += int(consts["par_x"].shape[0])
+    return rows
+
+
 def _empty_sched(n: int) -> dict:
     """Zero-length adaptive-schedule record (keys per
     `repro.core.run_state._SCHED_KEYS`); blocks append to it via
@@ -887,19 +899,22 @@ class Experiment:
     def _timed_scan(fn):
         """Telemetry shim over a cached jitted scan: the first (compiling)
         call lands in span ``scan/compile``, warm calls in
-        ``scan/execute``.  Disabled spans delegate straight through — no
-        sync, no clock read; enabled spans block on the output inside the
-        span (same values, the device sync is just forced before the
-        clock stops).  ``.lower`` is the jitted scan's, so the compiled
-        round program can be inspected."""
+        ``scan/execute``.  Disabled spans delegate straight through.  The
+        compile span blocks on the output (it runs once, in warm-up); the
+        execute span times the dispatch only and never syncs, so tracing
+        leaves the block's schedule as it is — the device side is read
+        from a profiler trace.  ``.lower`` is the jitted scan's, so the
+        compiled round program can be inspected."""
         state = {"warm": False}
 
         def call(*args):
             if not obs_spans.enabled():
                 state["warm"] = True
                 return fn(*args)
-            name = "scan/execute" if state["warm"] else "scan/compile"
-            with obs_spans.span(name):
+            if state["warm"]:
+                with obs_spans.span("scan/execute"):
+                    return fn(*args)
+            with obs_spans.span("scan/compile"):
                 out = jax.block_until_ready(fn(*args))
             state["warm"] = True
             return out
@@ -1103,22 +1118,25 @@ class Experiment:
                 raise ValueError(
                     "state was initialized with collect=False; re-init "
                     "with collect=True to evaluate during the run")
-        # detached generator: the stream position lives in the state, not
-        # in this Experiment, so replaying a restored block is hermetic
-        rng = np.random.default_rng()
-        rng.bit_generator.state = state.rng_state
-        if state.mode == "multi_channel":
-            return self._block_multi_channel(state, rng)
-        r0 = state.rounds_done
-        K = int(n_rounds) if n_rounds is not None else (
-            self.checkpoint_every or state.iterations)
-        if K < 1:
-            raise ValueError(f"n_rounds={K} must be >= 1")
-        K = min(K, state.iterations - r0)
-        lrs = self._lr_schedule_range(r0, r0 + K)
-        if state.mode == "multi":
-            return self._block_multi(state, rng, K, lrs)
-        return self._block_single(state, rng, K, lrs, eval_fn, eval_every)
+        with obs_spans.span("block/run", cursor=state.rounds_done):
+            # detached generator: the stream position lives in the state,
+            # not in this Experiment, so replaying a restored block is
+            # hermetic
+            rng = np.random.default_rng()
+            rng.bit_generator.state = state.rng_state
+            if state.mode == "multi_channel":
+                return self._block_multi_channel(state, rng)
+            r0 = state.rounds_done
+            K = int(n_rounds) if n_rounds is not None else (
+                self.checkpoint_every or state.iterations)
+            if K < 1:
+                raise ValueError(f"n_rounds={K} must be >= 1")
+            K = min(K, state.iterations - r0)
+            lrs = self._lr_schedule_range(r0, r0 + K)
+            if state.mode == "multi":
+                return self._block_multi(state, rng, K, lrs)
+            return self._block_single(state, rng, K, lrs, eval_fn,
+                                      eval_every)
 
     def _block_single(self, state: RunState, rng, K: int, lrs, eval_fn,
                       eval_every: int) -> RunState:
@@ -1126,65 +1144,74 @@ class Experiment:
         the traced-channel (and adaptive-controller) path chained through
         the state's `TraceState` / estimator stats / control values."""
         r0 = state.rounds_done
-        consts = self._get_consts()
-        trace_new = state.trace
-        est_new, controls_new = state.est, state.controls
-        sched_new = state.sched
-        if self.channel is None:
-            times = sample_round_times(
-                self.nodes, np.asarray(self.loads, float), rng, K)
-            xs = self._scan_xs(times, lrs)
+        with obs_spans.span("block/prepare"):
+            consts = self._get_consts()
             if obs_spans.enabled():
-                self._attr_blocks.append({"times": times, "active": None})
-        else:
-            with obs_spans.span("trace/generate"):
-                trace_block, trace_new = generate_trace_block(
-                    self.nodes, self.channel, K, state.trace)
-            if self.adaptive:
-                est = OnlineChannelEstimator(
-                    self.nodes, **self.scheme_params_estimator_kwargs())
-                est.load_state_dict(state.est)
-                seg = plan_segment(self, est, trace_block, r0, r0 + K,
-                                   state.controls, rng)
-                xs = (jnp.asarray(seg.times, jnp.float32),
-                      jnp.asarray(lrs), jnp.asarray(seg.active))
-                if self.step_kind == "adaptive_coded":
-                    consts = dict(consts)
-                    consts["gmask_blocks"] = seg.gmask_blocks
-                    xs = xs + (jnp.asarray(seg.t_star_r, jnp.float32),
-                               jnp.asarray(seg.block_idx))
-                else:
-                    xs = xs + (jnp.asarray(seg.n_wait_r),)
-                est_new = est.state_dict()
-                controls_new = seg.controls
-                sched_new = _append_sched(state.sched, seg)
+                obs_spans.count("round/rows", K * _round_rows(consts))
+            trace_new = state.trace
+            est_new, controls_new = state.est, state.controls
+            sched_new = state.sched
+            if self.channel is None:
+                times = sample_round_times(
+                    self.nodes, np.asarray(self.loads, float), rng, K)
+                xs = self._scan_xs(times, lrs)
                 if obs_spans.enabled():
-                    self._attr_blocks.append({
-                        "times": np.asarray(seg.times),
-                        "active": np.asarray(seg.active),
-                        "t_star_r": (np.asarray(seg.t_star_r)
-                                     if self.step_kind == "adaptive_coded"
-                                     else None),
-                        "n_wait_r": (np.asarray(seg.n_wait_r)
-                                     if self.step_kind != "adaptive_coded"
-                                     else None)})
+                    self._attr_blocks.append({"times": times,
+                                              "active": None})
             else:
-                times = sample_round_times_traced(
-                    self.nodes, np.asarray(self.loads, float), rng,
-                    trace_block)
-                xs = (jnp.asarray(times, jnp.float32), jnp.asarray(lrs),
-                      jnp.asarray(trace_block.active, jnp.float32))
-                if obs_spans.enabled():
-                    self._attr_blocks.append({
-                        "times": times,
-                        "active": np.asarray(trace_block.active)})
-        fault_xs, fault_rng_new = self._fault_rows(state, K)
-        xs = xs + fault_xs
-        scan_fn = self._get_scan(state.collect)
-        carry_out, per_round = scan_fn(
-            consts, self._carry0(state.theta, state.lr_scale,
-                                 state.theta_prev), xs)
-        theta = carry_out[0]
+                with obs_spans.span("trace/generate"):
+                    trace_block, trace_new = generate_trace_block(
+                        self.nodes, self.channel, K, state.trace)
+                if self.adaptive:
+                    est = OnlineChannelEstimator(
+                        self.nodes, **self.scheme_params_estimator_kwargs())
+                    est.load_state_dict(state.est)
+                    seg = plan_segment(self, est, trace_block, r0, r0 + K,
+                                       state.controls, rng)
+                    xs = (jnp.asarray(seg.times, jnp.float32),
+                          jnp.asarray(lrs), jnp.asarray(seg.active))
+                    if self.step_kind == "adaptive_coded":
+                        consts = dict(consts)
+                        consts["gmask_blocks"] = seg.gmask_blocks
+                        xs = xs + (jnp.asarray(seg.t_star_r, jnp.float32),
+                                   jnp.asarray(seg.block_idx))
+                    else:
+                        xs = xs + (jnp.asarray(seg.n_wait_r),)
+                    est_new = est.state_dict()
+                    controls_new = seg.controls
+                    sched_new = _append_sched(state.sched, seg)
+                    if obs_spans.enabled():
+                        self._attr_blocks.append({
+                            "times": np.asarray(seg.times),
+                            "active": np.asarray(seg.active),
+                            "t_star_r": (np.asarray(seg.t_star_r)
+                                         if self.step_kind == "adaptive_coded"
+                                         else None),
+                            "n_wait_r": (np.asarray(seg.n_wait_r)
+                                         if self.step_kind != "adaptive_coded"
+                                         else None)})
+                else:
+                    times = sample_round_times_traced(
+                        self.nodes, np.asarray(self.loads, float), rng,
+                        trace_block)
+                    xs = (jnp.asarray(times, jnp.float32), jnp.asarray(lrs),
+                          jnp.asarray(trace_block.active, jnp.float32))
+                    if obs_spans.enabled():
+                        self._attr_blocks.append({
+                            "times": times,
+                            "active": np.asarray(trace_block.active)})
+            fault_xs, fault_rng_new = self._fault_rows(state, K)
+            xs = xs + fault_xs
+            scan_fn = self._get_scan(state.collect)
+            carry0 = self._carry0(state.theta, state.lr_scale,
+                                  state.theta_prev)
+        carry_out, per_round = scan_fn(consts, carry0, xs)
+        with obs_spans.span("block/fetch"):
+            t_rounds_b = np.asarray(per_round[0], np.float64)
+            n_ret_b = np.asarray(per_round[1])
+            n_masked_b = np.asarray(per_round[2], np.int64)
+            skipped_b = np.asarray(per_round[3], np.int64)
+            lr_scale = float(carry_out[1])
         losses_new, accs_new = state.losses, state.accs
         if state.collect:
             thetas = per_round[4]
@@ -1198,22 +1225,19 @@ class Experiment:
                     acc_b[k] = float(acc)
             losses_new = np.concatenate([state.losses, loss_b])
             accs_new = np.concatenate([state.accs, acc_b])
-        return dataclasses.replace(
-            state, rounds_done=r0 + K, theta=theta,
-            rng_state=rng.bit_generator.state, trace=trace_new,
-            est=est_new, controls=controls_new,
-            t_rounds=np.concatenate(
-                [state.t_rounds, np.asarray(per_round[0], np.float64)]),
-            n_ret=np.concatenate(
-                [state.n_ret, np.asarray(per_round[1])]),
-            losses=losses_new, accs=accs_new, sched=sched_new,
-            lr_scale=float(carry_out[1]),
-            n_masked=np.concatenate(
-                [state.n_masked, np.asarray(per_round[2], np.int64)]),
-            skipped=np.concatenate(
-                [state.skipped, np.asarray(per_round[3], np.int64)]),
-            theta_prev=(carry_out[2] if self.stale_faults else None),
-            fault_rng_state=fault_rng_new)
+        with obs_spans.span("block/state"):
+            return dataclasses.replace(
+                state, rounds_done=r0 + K, theta=carry_out[0],
+                rng_state=rng.bit_generator.state, trace=trace_new,
+                est=est_new, controls=controls_new,
+                t_rounds=np.concatenate([state.t_rounds, t_rounds_b]),
+                n_ret=np.concatenate([state.n_ret, n_ret_b]),
+                losses=losses_new, accs=accs_new, sched=sched_new,
+                lr_scale=lr_scale,
+                n_masked=np.concatenate([state.n_masked, n_masked_b]),
+                skipped=np.concatenate([state.skipped, skipped_b]),
+                theta_prev=(carry_out[2] if self.stale_faults else None),
+                fault_rng_state=fault_rng_new)
 
     def _block_multi(self, state: RunState, rng, K: int, lrs) -> RunState:
         """K rounds of ALL stationary realizations in one vmapped scan

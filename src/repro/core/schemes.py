@@ -226,18 +226,21 @@ class CodedScheme(Scheme):
                 keys, x_enc, exp.y, w_stack, exp.u,
                 use_pallas=exp.kernel_backend == "pallas",
                 interpret=exp._interpret)
-        if exp.secure_aggregation:
-            # paper §VI future work: the server only ever sees masked
-            # uploads; pairwise masks cancel in the sum (core/secure_agg.py)
-            from repro.core import secure_agg
-            skey = jax.random.PRNGKey(fl.seed + 1234)
-            masked = [secure_agg.mask_parity(
-                skey, j, exp.n,
-                encoding.LocalParity(x=stacked.x[j], y=stacked.y[j]))
-                for j in range(exp.n)]
-            exp.parity = secure_agg.secure_aggregate(masked)
-        else:
-            exp.parity = encoding.aggregate_parity_stacked(stacked)
+            if exp.secure_aggregation:
+                # paper §VI future work: the server only ever sees masked
+                # uploads; pairwise masks cancel in the sum
+                # (core/secure_agg.py)
+                from repro.core import secure_agg
+                skey = jax.random.PRNGKey(fl.seed + 1234)
+                masked = [secure_agg.mask_parity(
+                    skey, j, exp.n,
+                    encoding.LocalParity(x=stacked.x[j], y=stacked.y[j]))
+                    for j in range(exp.n)]
+                exp.parity = secure_agg.secure_aggregate(masked)
+            else:
+                exp.parity = encoding.aggregate_parity_stacked(stacked)
+            if obs_spans.enabled():
+                jax.block_until_ready((exp.parity.x, exp.parity.y))
         # one-time parity upload overhead: clients upload u*(q+c) scalars in
         # parallel; expected transmissions 1/(1-p) (paper Fig 4a inset).
         # NodeDelayParams validates p < 1 at construction, so the expected

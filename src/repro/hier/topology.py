@@ -137,6 +137,12 @@ def _coded_static_names() -> tuple[str, ...]:
                  if schemes_registry.get_scheme(n).step_kind == "coded")
 
 
+def _upload_bytes(*arrays) -> int:
+    """Bytes that f32 uploads of `arrays` move from host to device; an
+    input already on the device moves none."""
+    return sum(4 * a.size for a in arrays if isinstance(a, np.ndarray))
+
+
 class HierExperiment:
     """One runnable hierarchical deployment (module docstring).
 
@@ -298,6 +304,8 @@ class HierExperiment:
                 agg = encoding.aggregate_parity_stacked(stacked)
                 px = px + agg.x
                 py = py + agg.y
+            if obs_spans.enabled():
+                jax.block_until_ready((px, py))
         r_mass = float(np.sum(loads * p_ret))
         w_f = sampling.parity_reweight(m_s, r_mass, self.sample_fraction)
         # one-time parity upload overhead (flat CodedScheme formula over
@@ -399,25 +407,27 @@ class HierExperiment:
         if K < 1:
             raise ValueError(f"n_rounds={K} must be >= 1")
         K = min(K, state.iterations - r0)
-        # both streams consume a FIXED per-round layout (delay: one
-        # 3-draw row per round; sampling: one uniform row per round), so
-        # the stream position depends only on the global round cursor —
-        # every block partition of a run, and every kill/resume point,
-        # replays bit-identically (stronger than the flat engine's
-        # per-block draw layout)
-        times = np.concatenate(
-            [sample_round_times_stacked(self._prm, self._pop_loads, rng, 1)
-             for _ in range(K)], axis=0)
-        cohort = sampling.sample_cohort_rows(srng, K, self.n,
-                                             self.sample_fraction)
-        if obs_spans.enabled():
-            self._attr_blocks.append({"times": times, "active": cohort})
-        lrs = self._lr_schedule_range(r0, r0 + K)
-        l2 = jnp.float32(self.train.l2_reg)
-        m = jnp.float32(self.m)
-        theta = state.theta
-        n_ret_blk = np.zeros(K, np.int32)
-        with obs_spans.span("hier/round_block"):
+        with obs_spans.span("hier/round_block", cursor=r0):
+            # both streams consume a FIXED per-round layout (delay: one
+            # 3-draw row per round; sampling: one uniform row per round),
+            # so the stream position depends only on the global round
+            # cursor — every block partition of a run, and every
+            # kill/resume point, replays bit-identically (stronger than
+            # the flat engine's per-block draw layout)
+            with obs_spans.span("hier/sample"):
+                times = np.concatenate(
+                    [sample_round_times_stacked(self._prm, self._pop_loads,
+                                                rng, 1)
+                     for _ in range(K)], axis=0)
+                cohort = sampling.sample_cohort_rows(srng, K, self.n,
+                                                     self.sample_fraction)
+            if obs_spans.enabled():
+                self._attr_blocks.append({"times": times, "active": cohort})
+            lrs = self._lr_schedule_range(r0, r0 + K)
+            l2 = jnp.float32(self.train.l2_reg)
+            m = jnp.float32(self.m)
+            theta = state.theta
+            n_ret_blk = np.zeros(K, np.int32)
             for k in range(K):
                 g = jnp.zeros((self.q, self.c), jnp.float32)
                 returned = 0
@@ -426,21 +436,29 @@ class HierExperiment:
                     ret = (row <= plan.t_star) & cohort[k, plan.lo:plan.hi]
                     returned += int(np.sum(ret))
                     xb, yb = self._data(plan.lo, plan.hi)
-                    g = g + self._shard_fn(
-                        jnp.asarray(xb, jnp.float32),
-                        jnp.asarray(yb, jnp.float32),
-                        plan.gmask, jnp.asarray(ret, jnp.float32), theta,
-                        plan.parity_x, plan.parity_y,
-                        jnp.float32(plan.parity_weight))
+                    with obs_spans.span("hier/shard_upload"):
+                        xd = jnp.asarray(xb, jnp.float32)
+                        yd = jnp.asarray(yb, jnp.float32)
+                        rd = jnp.asarray(ret, jnp.float32)
+                    if obs_spans.enabled():
+                        obs_spans.count("hier/h2d_bytes",
+                                        _upload_bytes(xb, yb, ret))
+                        obs_spans.count("round/rows", (plan.hi - plan.lo)
+                                        * self.l + plan.u)
+                    with obs_spans.span("hier/shard_round"):
+                        g = g + self._shard_fn(
+                            xd, yd, plan.gmask, rd, theta,
+                            plan.parity_x, plan.parity_y,
+                            jnp.float32(plan.parity_weight))
                 theta = theta - jnp.float32(lrs[k]) * (g / m + l2 * theta)
                 n_ret_blk[k] = returned
-        return dataclasses.replace(
-            state, rounds_done=r0 + K, theta=theta,
-            rng_state=rng.bit_generator.state,
-            sample_rng_state=srng.bit_generator.state,
-            t_rounds=np.concatenate(
-                [state.t_rounds, np.full(K, self.t_round, np.float64)]),
-            n_ret=np.concatenate([state.n_ret, n_ret_blk]))
+            return dataclasses.replace(
+                state, rounds_done=r0 + K, theta=theta,
+                rng_state=rng.bit_generator.state,
+                sample_rng_state=srng.bit_generator.state,
+                t_rounds=np.concatenate(
+                    [state.t_rounds, np.full(K, self.t_round, np.float64)]),
+                n_ret=np.concatenate([state.n_ret, n_ret_blk]))
 
     # ------------------------------------------------------------ telemetry
     def attribution(self, k: int = 3) -> dict:

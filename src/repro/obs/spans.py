@@ -1,31 +1,65 @@
-"""Zero-overhead-when-disabled span timers with a thread-safe collector.
+"""Span timers and counters, zero-cost when disabled, with a thread-safe
+collector.
 
 The runtime's hot paths are annotated with ``with span("solver/two_step")``
-blocks; when the module flag is off (the default) ``__enter__`` is a single
-flag check — no clock read, no lock, no allocation beyond the span object
-itself — so un-instrumented runs pay nothing measurable.  `enable()` turns
+blocks and ``count("round/rows", n)`` calls; when the module flag is off
+(the default) each is a single flag check: no import, no clock read, no
+lock, no allocation beyond the span object itself.  `enable()` turns
 every span in the process into a wall-clock measurement recorded in one
-in-process collector keyed by span name; `totals()` snapshots it.
+in-process collector keyed by span name (`totals()`), and every counter
+into a running sum (`counters()`).  An enabled span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so while a profiler trace
+is being taken it lands on the calling thread's host timeline beside
+the device ops; the parent span of a block carries the block's round
+cursor as trace metadata (``cursor``).
 
-Span names in the runtime (all host-side, wrapping whole setup phases or
-whole compiled blocks — never per-round work, so overhead stays bounded by
-the block count, not the round count):
+Span names in the runtime (all host-side; the per-round ones sit inside
+the hierarchical loop, whose rounds are host work anyway):
 
   ==========================  ==============================================
   ``setup/experiment``        whole scheme setup (`Experiment.__init__`)
   ``solver/two_step``         two-step load-allocation solve
-  ``encode/parity``           batched/streamed parity encode
+  ``encode/parity``           parity encode and aggregate, ending in a
+                              sync on the parity set
   ``trace/generate``          channel-trace block generation
-  ``scan/compile``            first (compiling) call of a cached scan
-  ``scan/execute``            warm calls of that scan
+  ``block/run``               one flat `run_block` (``cursor``)
+  ``block/prepare``           its delay draws, scan inputs, fault rows,
+                              consts and uploads, before dispatch
+  ``scan/compile``            first (compiling) call of a cached scan,
+                              synced
+  ``scan/execute``            warm calls of that scan: the dispatch only
+  ``block/fetch``             per-round outputs to the host: the wait
+                              for the scan plus the copies
+  ``block/state``             the run history grown and the new state
   ``checkpoint/save``         `save_state` (atomic npz write)
   ``checkpoint/restore``      `restore_state` (load + digest verify)
   ``hier/shard_setup``        one edge aggregator's deployment setup
-  ``hier/round_block``        one hierarchical `run_block`
+  ``hier/round_block``        one hierarchical `run_block` (``cursor``)
+  ``hier/sample``             its delay and cohort draws
+  ``hier/shard_upload``       one shard's block and return mask to the
+                              device, per round
+  ``hier/shard_round``        one shard's round dispatch, per round
   ``journal/append``          run-journal block append
   ``service/block``           one `ExperimentService` block advance
   ``service/ckpt_save``       the service's view of one checkpoint save
   ``service/backoff``         retry backoff sleeps
+  ==========================  ==============================================
+
+No span forces a device sync except ``scan/compile`` (once per cached
+scan) and ``encode/parity`` (set-up); device time is read from a
+profiler trace, not from these clocks.
+
+Counters (``{name: {"events", "total"}}``):
+
+  ==========================  ==============================================
+  ``round/rows``              rows the compiled round reads, per round:
+                              the rows of the step's gradient tensor, the
+                              parity set included (flat single-trajectory
+                              blocks), or each shard's n_s * l client rows
+                              plus its u_s parity rows (hierarchical)
+  ``hier/h2d_bytes``          bytes of the host arrays a hierarchical round
+                              hands to the device (shard blocks and return
+                              masks, as f32)
   ==========================  ==============================================
 
 Timing never touches an RNG stream or any value that flows into a
@@ -40,7 +74,8 @@ import threading
 import time
 
 __all__ = ["span", "enable", "disable", "enabled", "reset", "record",
-           "totals", "write_json", "collecting", "SPANS_NAME"]
+           "totals", "count", "counters", "write_json", "collecting",
+           "SPANS_NAME"]
 
 #: filename `write_json` conventionally uses inside a run directory
 SPANS_NAME = "spans.json"
@@ -49,6 +84,10 @@ _enabled = False
 _lock = threading.Lock()
 #: name -> [count, total_s, min_s, max_s]
 _records: "dict[str, list]" = {}
+#: name -> [events, total]
+_counters: "dict[str, list]" = {}
+#: `jax.profiler.TraceAnnotation`, imported by the first enabled span
+_annotation = None
 
 
 def enabled() -> bool:
@@ -69,9 +108,11 @@ def disable() -> None:
 
 
 def reset() -> None:
-    """Drop all collected records (the enable flag is left as is)."""
+    """Drop all collected records and counters (the enable flag is left
+    as is)."""
     with _lock:
         _records.clear()
+        _counters.clear()
 
 
 def record(name: str, seconds: float) -> None:
@@ -98,6 +139,35 @@ def totals() -> dict:
                 for name, rec in sorted(_records.items())}
 
 
+def count(name: str, value) -> None:
+    """Add `value` to counter `name` when spans are enabled (thread-safe);
+    a single flag check otherwise."""
+    if not _enabled:
+        return
+    with _lock:
+        rec = _counters.get(name)
+        if rec is None:
+            _counters[name] = [1, value]
+        else:
+            rec[0] += 1
+            rec[1] += value
+
+
+def counters() -> dict:
+    """Snapshot the counters: {name: {events, total}}, names sorted."""
+    with _lock:
+        return {name: {"events": int(rec[0]), "total": rec[1]}
+                for name, rec in sorted(_counters.items())}
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
 def write_json(path: str) -> str:
     """Write `totals()` as pretty JSON (a run dir's ``spans.json``)."""
     with open(path, "w") as fh:
@@ -110,21 +180,33 @@ class span:
     """``with span("solver/two_step"): ...`` — wall-clock one region.
 
     When the module flag is off the context manager is inert (no clock
-    read).  ``force=True`` measures regardless of the flag — the duration
-    lands in ``self.elapsed_s`` for the caller, but is only folded into
-    the global collector when the flag is on (the `ExperimentService`
+    read, no trace annotation).  When it is on, the region also enters a
+    ``jax.profiler.TraceAnnotation`` of the span's name, with ``cursor``
+    (a block's first round) as its metadata when given.  ``force=True``
+    measures regardless of the flag — the duration lands in
+    ``self.elapsed_s`` for the caller, but is only folded into the global
+    collector, and annotated, when the flag is on (the `ExperimentService`
     uses this for its always-on per-run health timings).
     """
-    __slots__ = ("name", "elapsed_s", "_t0", "_force")
+    __slots__ = ("name", "elapsed_s", "_t0", "_force", "_cursor", "_ann")
 
-    def __init__(self, name: str, *, force: bool = False):
+    def __init__(self, name: str, *, force: bool = False,
+                 cursor: "int | None" = None):
         self.name = name
         self.elapsed_s = None
         self._t0 = None
         self._force = force
+        self._cursor = cursor
+        self._ann = None
 
     def __enter__(self) -> "span":
-        if _enabled or self._force:
+        if _enabled:
+            ann = _trace_annotation()
+            self._ann = (ann(self.name) if self._cursor is None
+                         else ann(self.name, cursor=int(self._cursor)))
+            self._ann.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._force:
             self._t0 = time.perf_counter()
         return self
 
@@ -134,13 +216,17 @@ class span:
             self._t0 = None
             if _enabled:
                 record(self.name, self.elapsed_s)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         return False
 
 
 @contextlib.contextmanager
 def collecting(fresh: bool = True):
     """Enable spans for the duration of the block, restoring the previous
-    flag afterwards; ``fresh`` clears the collector first.  Yields the
+    flag afterwards; ``fresh`` clears the collector and the counters
+    first.  Yields the
     module so ``with collecting() as spans: ... spans.totals()`` reads
     naturally."""
     global _enabled

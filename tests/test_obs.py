@@ -101,6 +101,87 @@ def test_write_json_roundtrip(tmp_path):
     assert loaded["encode/parity"]["count"] == 1
 
 
+class _Annotation:
+    """Stands in for `jax.profiler.TraceAnnotation`, logging its use."""
+    log: list = []
+
+    def __init__(self, name, **meta):
+        self.name, self.meta = name, meta
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.meta))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.meta))
+
+
+def test_enabled_span_enters_trace_annotation(monkeypatch):
+    monkeypatch.setattr(obs_spans, "_annotation", _Annotation)
+    monkeypatch.setattr(_Annotation, "log", [])
+    with obs_spans.span("block/run", cursor=40):
+        pass
+    with obs_spans.span("service/block", force=True):
+        pass
+    assert _Annotation.log == []
+    obs_spans.enable()
+    with obs_spans.span("block/run", cursor=40):
+        with obs_spans.span("block/prepare"):
+            pass
+    assert _Annotation.log == [
+        ("enter", "block/run", {"cursor": 40}),
+        ("enter", "block/prepare", {}), ("exit", "block/prepare", {}),
+        ("exit", "block/run", {"cursor": 40})]
+
+
+def test_counters_fold_and_reset_with_collecting():
+    obs_spans.count("round/rows", 5)
+    assert obs_spans.counters() == {}
+    with obs_spans.collecting():
+        obs_spans.count("round/rows", 5)
+        obs_spans.count("round/rows", 7)
+        obs_spans.count("hier/h2d_bytes", 4096)
+        assert obs_spans.counters() == {
+            "hier/h2d_bytes": {"events": 1, "total": 4096},
+            "round/rows": {"events": 2, "total": 12}}
+    obs_spans.count("round/rows", 1)      # disabled again: not counted
+    assert obs_spans.counters()["round/rows"]["total"] == 12
+    with obs_spans.collecting():
+        assert obs_spans.counters() == {}
+    # totals() keeps its schema: counters never show up there
+    assert obs_spans.totals() == {}
+
+
+def test_scan_execute_never_blocks(monkeypatch):
+    import jax
+    xs, ys = _data()
+    obs_spans.enable()
+    exp = api.build_experiment(_spec(), xs, ys)
+    state = exp.run_block(exp.init_state(12))        # scan/compile
+    calls = []
+    sync = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(1) or sync(x))
+    state = exp.run_block(exp.run_block(state))
+    assert calls == []
+    rec = obs_spans.totals()
+    assert rec["scan/compile"]["count"] == 1
+    assert rec["scan/execute"]["count"] == 2
+    for name in ("block/run", "block/prepare", "block/fetch", "block/state"):
+        assert rec[name]["count"] == 3
+
+
+def test_round_rows_counter_matches_shapes():
+    xs, ys = _data()
+    with obs_spans.collecting() as mod:
+        exp = api.build_experiment(_spec(), xs, ys)
+        exp.run(12)
+        got = mod.counters()["round/rows"]
+    # the fused coded tensor: n client row blocks and the parity set,
+    # each padded to L = max(largest load, u) points
+    L = max(int(exp.loads.max()), exp.u)
+    assert got == {"events": 3, "total": 12 * (exp.n + 1) * L}
+
+
 # ---------------------------------------------------------------------------
 # the hard invariant: telemetry never perturbs a trajectory
 # ---------------------------------------------------------------------------
@@ -154,6 +235,17 @@ def test_hier_telemetry_on_off_bit_identical(tmp_path):
     assert all(len(e["t_star_s"]) == 2 for e in events)
     attr = exp_on.attribution()
     assert set(attr) == {0, 1}
+    # each round uploads every shard's f32 block and return mask:
+    # 2 shards x 6 clients x (4 x (6 + 2) points + 1 mask entry) x 4 B
+    counts = obs_spans.counters()
+    assert counts["hier/h2d_bytes"] == {
+        "events": 9 * 2, "total": 9 * 2 * 6 * (4 * 8 + 1) * 4}
+    # and reads each shard's 6 x 4 client rows plus u_s = 6 parity rows
+    assert counts["round/rows"] == {"events": 9 * 2,
+                                    "total": 9 * 2 * (6 * 4 + 6)}
+    names = set(obs_spans.totals())
+    assert {"hier/round_block", "hier/sample", "hier/shard_upload",
+            "hier/shard_round"} <= names
 
 
 # ---------------------------------------------------------------------------
