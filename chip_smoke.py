@@ -140,9 +140,10 @@ def run_cell(spec, xs, ys) -> dict:
     # `checkpoint_every` rounds through the cached jitted scan
     k, rounds = spec.checkpoint_every, ROUNDS
     t0 = time.perf_counter()
-    compiled = exp._get_scan(False).lower(
-        consts, exp._carry0(jnp.zeros((exp.q, exp.c), jnp.float32), 1.0),
-        exp._scan_xs(np.zeros((k, exp.n)), np.zeros(k))).compile()
+    scan_fn, thetas, words = exp._prepare_scan(
+        False, (np.zeros((k, exp.n)), np.zeros(k, np.float32)), 1.0,
+        jnp.zeros((exp.q, exp.c), jnp.float32))
+    compiled = scan_fn.lower(consts, thetas, words).compile()
     compile_s = time.perf_counter() - t0
     t0 = time.perf_counter()
     res = exp.run(rounds)

@@ -533,6 +533,65 @@ def _round_rows(consts: dict) -> int:
     return rows
 
 
+def _pack_inputs(leaves) -> "tuple[np.ndarray, tuple]":
+    """A block's host inputs as one int32 word buffer, for one upload.
+
+    Each leaf is canonicalised as `jnp.asarray` does with 64-bit types
+    off (floats to f32, integers to i32; a bool becomes a 0/1 word) and
+    laid end to end.  Returns ``(words, layout)``; `layout` holds each
+    leaf's ``(shape, dtype name)`` and is what `_unpack_inputs` needs."""
+    words, layout = [], []
+    for leaf in leaves:
+        a = np.asarray(leaf)
+        if a.dtype == np.bool_:
+            name, w = "bool", a.astype(np.int32)
+        elif a.dtype.kind == "f":
+            name, w = "float32", a.astype(np.float32).view(np.int32)
+        elif a.dtype.kind == "i":
+            name, w = "int32", a.astype(np.int32)
+        else:
+            raise TypeError(f"cannot pack a {a.dtype} scan input")
+        layout.append((a.shape, name))
+        words.append(w.reshape(-1))
+    return np.concatenate(words), tuple(layout)
+
+
+def _unpack_inputs(words, layout) -> list:
+    """Device side of `_pack_inputs`: the leaves again, bit for bit,
+    by static slices, bitcasts and reshapes."""
+    leaves, off = [], 0
+    for shape, name in layout:
+        size = math.prod(shape)
+        w = words[off:off + size].reshape(shape)
+        off += size
+        if name == "float32":
+            w = jax.lax.bitcast_convert_type(w, jnp.float32)
+        elif name == "bool":
+            w = w != 0
+        leaves.append(w)
+    return leaves
+
+
+def _pack_outputs(per_round, lr_scale) -> jnp.ndarray:
+    """A block's host-bound outputs as one (4K + 1,) int32 vector, for
+    one fetch: the per-round t_round (f32 bits), n_ret, n_masked and
+    skipped, then the carry's lr_scale (f32 bits)."""
+    t_round, n_ret, n_masked, skipped = per_round
+    bits = jax.lax.bitcast_convert_type
+    return jnp.concatenate([bits(t_round, jnp.int32), n_ret, n_masked,
+                            skipped, bits(lr_scale, jnp.int32)[None]])
+
+
+def _unpack_outputs(words: np.ndarray, K: int) -> tuple:
+    """Host side of `_pack_outputs`: ``(t_rounds f64, n_ret i32,
+    n_masked i64, skipped i64, lr_scale float)``."""
+    return (words[:K].view(np.float32).astype(np.float64),
+            words[K:2 * K],
+            words[2 * K:3 * K].astype(np.int64),
+            words[3 * K:4 * K].astype(np.int64),
+            float(words[4 * K:].view(np.float32)[0]))
+
+
 def _empty_sched(n: int) -> dict:
     """Zero-length adaptive-schedule record (keys per
     `repro.core.run_state._SCHED_KEYS`); blocks append to it via
@@ -922,18 +981,30 @@ class Experiment:
         call.lower = fn.lower
         return call
 
-    def _get_scan(self, collect_theta: bool):
-        """jit'd `lax.scan` over a per-round input pytree, cached per
-        (scheme, collect).  The xs tuple's structure follows the step's
-        static configuration (see `build_step`)."""
-        cache_key = (self.scheme, collect_theta)
+    def _get_scan(self, collect_theta: bool, layout: tuple):
+        """jit'd `lax.scan` of one flat block, cached per (scheme, collect,
+        layout).  ``fn(consts, thetas, words)``: `thetas` is ``(theta,)``,
+        or ``(theta, theta_prev)`` with stale replay; `words` is the
+        `_pack_inputs` buffer of the xs leaves (their structure follows
+        the step's static configuration, see `build_step`) and, last, the
+        carry's lr_scale.  Returns ``(thetas, packed, collected)``:
+        the new `thetas`, the `_pack_outputs` vector, and ``(thetas per
+        round,)`` with `collect_theta`, else ``()``."""
+        cache_key = (self.scheme, collect_theta, layout)
         fn = self._scan_cache.get(cache_key)
         if fn is None:
             step = build_step(self.step_static(collect_theta))
-            fn = self._timed_scan(
-                jax.jit(lambda consts, carry0, xs:
-                        jax.lax.scan(lambda c, inp: step(consts, c, inp),
-                                     carry0, xs)))
+
+            def block(consts, thetas, words):
+                *xs, lr_scale = _unpack_inputs(words, layout)
+                carry0 = (thetas[0], lr_scale) + tuple(thetas[1:])
+                carry, per_round = jax.lax.scan(
+                    lambda c, inp: step(consts, c, inp), carry0, tuple(xs))
+                return ((carry[0],) + carry[2:],
+                        _pack_outputs(per_round[:4], carry[1]),
+                        per_round[4:])
+
+            fn = self._timed_scan(jax.jit(block))
             self._scan_cache[cache_key] = fn
         return fn
 
@@ -942,10 +1013,21 @@ class Experiment:
             self._consts = self.build_consts()
         return self._consts
 
-    def _scan_xs(self, times: np.ndarray, lrs: np.ndarray) -> tuple:
-        """Per-round scan inputs for one realization's pre-sampled delays."""
-        return (jnp.asarray(times, jnp.float32),
-                jnp.asarray(lrs, jnp.float32))
+    def _prepare_scan(self, collect: bool, xs: tuple, lr_scale, theta,
+                      theta_prev=None) -> tuple:
+        """A flat block's scan and its arguments: the host `xs` leaves and
+        the carry's `lr_scale` go to the device in one packed upload.
+        Returns ``(scan_fn, thetas, words)``; the caller dispatches
+        ``scan_fn(consts, thetas, words)`` and starts the packed output's
+        copy to the host at once, so it begins the moment the scan ends."""
+        words, layout = _pack_inputs(xs + (lr_scale,))
+        words = jax.device_put(words)
+        # the packed upload here and the packed fetch after the scan
+        obs_spans.count("block/transfers", 2)
+        thetas = (theta,)
+        if self.stale_faults:
+            thetas += (theta if theta_prev is None else theta_prev,)
+        return self._get_scan(collect, layout), thetas, words
 
     def _get_multi_scan(self):
         """jit'd vmapped scan for the stationary multi-realization mode,
@@ -978,8 +1060,9 @@ class Experiment:
     # ------------------------------------------------------- fault plumbing
     def _fault_rows(self, state: RunState, rounds: int):
         """Draw `rounds` rows of fault inputs from the state's dedicated
-        fault stream; returns ``(xs_extra, new_rng_state)`` — ``((), old
-        state)`` when return faults are off.  The stream is seeded off
+        fault stream; returns ``(xs_extra, new_rng_state)``, `xs_extra`
+        the host ``(codes, parity_bad)`` rows — ``((), old state)`` when
+        return faults are off.  The stream is seeded off
         ``fl.seed + 7717``, independent of both the delay-draw RNG and
         the channel-trace streams, so toggling faults never shifts the
         network realization a run faces."""
@@ -989,8 +1072,7 @@ class Experiment:
         frng.bit_generator.state = state.fault_rng_state
         fcodes, fpar = finject.sample_fault_rows(
             self.faults, frng, rounds, self.n)
-        return ((jnp.asarray(fcodes), jnp.asarray(fpar, jnp.float32)),
-                frng.bit_generator.state)
+        return (fcodes, fpar), frng.bit_generator.state
 
     def _carry0(self, theta, lr_scale, theta_prev=None):
         """Scan carry matching `build_step`'s static configuration."""
@@ -1154,7 +1236,7 @@ class Experiment:
             if self.channel is None:
                 times = sample_round_times(
                     self.nodes, np.asarray(self.loads, float), rng, K)
-                xs = self._scan_xs(times, lrs)
+                xs = (times, lrs)
                 if obs_spans.enabled():
                     self._attr_blocks.append({"times": times,
                                               "active": None})
@@ -1168,15 +1250,13 @@ class Experiment:
                     est.load_state_dict(state.est)
                     seg = plan_segment(self, est, trace_block, r0, r0 + K,
                                        state.controls, rng)
-                    xs = (jnp.asarray(seg.times, jnp.float32),
-                          jnp.asarray(lrs), jnp.asarray(seg.active))
+                    xs = (seg.times, lrs, seg.active)
                     if self.step_kind == "adaptive_coded":
                         consts = dict(consts)
                         consts["gmask_blocks"] = seg.gmask_blocks
-                        xs = xs + (jnp.asarray(seg.t_star_r, jnp.float32),
-                                   jnp.asarray(seg.block_idx))
+                        xs = xs + (seg.t_star_r, seg.block_idx)
                     else:
-                        xs = xs + (jnp.asarray(seg.n_wait_r),)
+                        xs = xs + (seg.n_wait_r,)
                     est_new = est.state_dict()
                     controls_new = seg.controls
                     sched_new = _append_sched(state.sched, seg)
@@ -1194,40 +1274,37 @@ class Experiment:
                     times = sample_round_times_traced(
                         self.nodes, np.asarray(self.loads, float), rng,
                         trace_block)
-                    xs = (jnp.asarray(times, jnp.float32), jnp.asarray(lrs),
-                          jnp.asarray(trace_block.active, jnp.float32))
+                    xs = (times, lrs,
+                          trace_block.active.astype(np.float32))
                     if obs_spans.enabled():
                         self._attr_blocks.append({
                             "times": times,
                             "active": np.asarray(trace_block.active)})
             fault_xs, fault_rng_new = self._fault_rows(state, K)
-            xs = xs + fault_xs
-            scan_fn = self._get_scan(state.collect)
-            carry0 = self._carry0(state.theta, state.lr_scale,
-                                  state.theta_prev)
-        carry_out, per_round = scan_fn(consts, carry0, xs)
+            scan_fn, thetas, words = self._prepare_scan(
+                state.collect, xs + fault_xs, state.lr_scale, state.theta,
+                state.theta_prev)
+        thetas, packed, collected = scan_fn(consts, thetas, words)
+        packed.copy_to_host_async()
         with obs_spans.span("block/fetch"):
-            t_rounds_b = np.asarray(per_round[0], np.float64)
-            n_ret_b = np.asarray(per_round[1])
-            n_masked_b = np.asarray(per_round[2], np.int64)
-            skipped_b = np.asarray(per_round[3], np.int64)
-            lr_scale = float(carry_out[1])
+            (t_rounds_b, n_ret_b, n_masked_b, skipped_b,
+             lr_scale) = _unpack_outputs(np.asarray(packed), K)
         losses_new, accs_new = state.losses, state.accs
         if state.collect:
-            thetas = per_round[4]
+            thetas_r = collected[0]
             loss_b = np.full(K, np.nan)
             acc_b = np.full(K, np.nan)
             for k in range(K):
                 it = r0 + k
                 if it % eval_every == 0 or it == state.iterations - 1:
-                    loss, acc = eval_fn(thetas[k])
+                    loss, acc = eval_fn(thetas_r[k])
                     loss_b[k] = float(loss)
                     acc_b[k] = float(acc)
             losses_new = np.concatenate([state.losses, loss_b])
             accs_new = np.concatenate([state.accs, acc_b])
         with obs_spans.span("block/state"):
             return dataclasses.replace(
-                state, rounds_done=r0 + K, theta=carry_out[0],
+                state, rounds_done=r0 + K, theta=thetas[0],
                 rng_state=rng.bit_generator.state, trace=trace_new,
                 est=est_new, controls=controls_new,
                 t_rounds=np.concatenate([state.t_rounds, t_rounds_b]),
@@ -1236,7 +1313,7 @@ class Experiment:
                 lr_scale=lr_scale,
                 n_masked=np.concatenate([state.n_masked, n_masked_b]),
                 skipped=np.concatenate([state.skipped, skipped_b]),
-                theta_prev=(carry_out[2] if self.stale_faults else None),
+                theta_prev=(thetas[1] if self.stale_faults else None),
                 fault_rng_state=fault_rng_new)
 
     def _block_multi(self, state: RunState, rng, K: int, lrs) -> RunState:
@@ -1289,57 +1366,48 @@ class Experiment:
             trace, _ = generate_trace_block(self.nodes, self.channel,
                                             state.iterations, tstate)
         consts = self._get_consts()
-        lrs = jnp.asarray(self._lr_schedule(state.iterations))
+        lrs = self._lr_schedule(state.iterations)
         sched_new = state.sched
         if self.adaptive:
             est = OnlineChannelEstimator(
                 self.nodes, **self.scheme_params_estimator_kwargs())
             seg = plan_segment(self, est, trace, 0, state.iterations,
                                self.scheme_obj.initial_controls(self), rng)
-            xs = (jnp.asarray(seg.times, jnp.float32), lrs,
-                  jnp.asarray(seg.active))
+            xs = (seg.times, lrs, seg.active)
             if self.step_kind == "adaptive_coded":
                 consts = dict(consts)
                 consts["gmask_blocks"] = seg.gmask_blocks
-                xs = xs + (jnp.asarray(seg.t_star_r, jnp.float32),
-                           jnp.asarray(seg.block_idx))
+                xs = xs + (seg.t_star_r, seg.block_idx)
             else:
-                xs = xs + (jnp.asarray(seg.n_wait_r),)
+                xs = xs + (seg.n_wait_r,)
             # the record kept is the LAST realization's plan, matching the
             # pre-RunState engine's `last_schedule` semantics
             sched_new = _append_sched(_empty_sched(self.n), seg)
         else:
             times = sample_round_times_traced(
                 self.nodes, np.asarray(self.loads, float), rng, trace)
-            xs = (jnp.asarray(times, jnp.float32), lrs,
-                  jnp.asarray(trace.active, jnp.float32))
+            xs = (times, lrs, trace.active.astype(np.float32))
         fault_xs, fault_rng_new = self._fault_rows(state,
                                                    state.iterations)
-        xs = xs + fault_xs
-        scan_fn = self._get_scan(False)
         theta0 = jnp.zeros((self.q, self.c), jnp.float32)
-        carry_out, per_round = scan_fn(
-            consts, self._carry0(theta0, 1.0), xs)
-        theta_r = carry_out[0]
+        scan_fn, thetas, words = self._prepare_scan(
+            False, xs + fault_xs, 1.0, theta0)
+        thetas, packed, _ = scan_fn(consts, thetas, words)
+        packed.copy_to_host_async()
+        t_rounds_r, n_ret_r, n_masked_r, skipped_r, lr_scale_r = (
+            _unpack_outputs(np.asarray(packed), state.iterations))
         lr_scale_new = np.asarray(state.lr_scale, np.float64).copy()
-        lr_scale_new[r] = float(carry_out[1])
+        lr_scale_new[r] = lr_scale_r
         return dataclasses.replace(
             state, realizations_done=r + 1,
             rounds_done=(r + 1) * state.iterations,
-            theta=state.theta.at[r].set(theta_r),
+            theta=state.theta.at[r].set(thetas[0]),
             rng_state=rng.bit_generator.state, sched=sched_new,
-            t_rounds=np.concatenate(
-                [state.t_rounds,
-                 np.asarray(per_round[0], np.float64)[None]]),
-            n_ret=np.concatenate(
-                [state.n_ret, np.asarray(per_round[1])[None]]),
+            t_rounds=np.concatenate([state.t_rounds, t_rounds_r[None]]),
+            n_ret=np.concatenate([state.n_ret, n_ret_r[None]]),
             lr_scale=lr_scale_new,
-            n_masked=np.concatenate(
-                [state.n_masked,
-                 np.asarray(per_round[2], np.int64)[None]]),
-            skipped=np.concatenate(
-                [state.skipped,
-                 np.asarray(per_round[3], np.int64)[None]]),
+            n_masked=np.concatenate([state.n_masked, n_masked_r[None]]),
+            skipped=np.concatenate([state.skipped, skipped_r[None]]),
             fault_rng_state=fault_rng_new)
 
     # ---------------------------------------------------- checkpoint/restore

@@ -23,13 +23,14 @@ the hierarchical loop, whose rounds are host work anyway):
                               sync on the parity set
   ``trace/generate``          channel-trace block generation
   ``block/run``               one flat `run_block` (``cursor``)
-  ``block/prepare``           its delay draws, scan inputs, fault rows,
-                              consts and uploads, before dispatch
+  ``block/prepare``           its delay draws, scan inputs, fault rows
+                              and consts, and one packed upload, before
+                              dispatch
   ``scan/compile``            first (compiling) call of a cached scan,
                               synced
   ``scan/execute``            warm calls of that scan: the dispatch only
   ``block/fetch``             per-round outputs to the host: the wait
-                              for the scan plus the copies
+                              for the scan plus one packed copy
   ``block/state``             the run history grown and the new state
   ``checkpoint/save``         `save_state` (atomic npz write)
   ``checkpoint/restore``      `restore_state` (load + digest verify)
@@ -57,6 +58,9 @@ Counters (``{name: {"events", "total"}}``):
                               parity set included (flat single-trajectory
                               blocks), or each shard's n_s * l client rows
                               plus its u_s parity rows (hierarchical)
+  ``block/transfers``         host-device transfers of a flat block's
+                              per-call inputs and outputs: 2 per call (one
+                              packed upload, one packed fetch)
   ``hier/h2d_bytes``          bytes of the host arrays a hierarchical round
                               hands to the device (shard blocks and return
                               masks, as f32)

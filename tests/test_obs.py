@@ -182,6 +182,23 @@ def test_round_rows_counter_matches_shapes():
     assert got == {"events": 3, "total": 12 * (exp.n + 1) * L}
 
 
+def test_block_transfers_counter_and_one_upload(monkeypatch):
+    from repro.core import fed_runtime
+    xs, ys = _data()
+    with obs_spans.collecting() as mod:
+        exp = api.build_experiment(_spec(), xs, ys)
+        exp.run(12)
+        assert mod.counters()["block/transfers"] == {"events": 3,
+                                                     "total": 2 * 3}
+    state = exp.run_block(exp.init_state(12))          # compiles
+    puts = []
+    put = fed_runtime.jax.device_put
+    monkeypatch.setattr(fed_runtime.jax, "device_put",
+                        lambda *a, **k: puts.append(1) or put(*a, **k))
+    state = exp.run_block(exp.run_block(state))
+    assert len(puts) == 2                              # one per call
+
+
 # ---------------------------------------------------------------------------
 # the hard invariant: telemetry never perturbs a trajectory
 # ---------------------------------------------------------------------------
