@@ -7,11 +7,12 @@
 For each of `n` seeds, one run of the cell with a window of `s` seconds
 (the benchmark's ``run_seconds`` by default, so the tail is compared
 where a run compares it): the program's readings.  For the first `k`
-seeds the same run also puts each reference variant in the program's
-place (the control: fp8 matmul operands and a float32 solver; the
-planted faults: half the batch, a drifting stream cursor), compared with
-the reference exactly as the program is.  One process; one JSON line per
-run on stdout.
+seeds the same run also puts each variant of the cell's kind
+(``VARIANTS`` of ``bench/kinds/<kind>.py``; for ``rff_coded`` the
+control, fp8 matmul operands and a float32 solver, and the planted
+faults, half the batch and a drifting stream cursor) in the program's
+place, compared with the reference exactly as the program is.  One
+process; one JSON line per run on stdout.
 """
 import argparse
 import json
@@ -41,11 +42,12 @@ def main(argv=None) -> int:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
             args.seconds = json.load(fh)["run_seconds"]
     log = lambda s: print(s, file=sys.stderr, flush=True)  # noqa: E731
-    kinds = tuple(k for k in harness.VARIANTS if k != "reference")
+    kind = harness.load_cell(args.workload)["kind"]
+    others = tuple(k for k in kind.VARIANTS if k != "reference")
     for i in range(args.seeds):
         seed = args.first_seed + i
         r = harness.run(args.workload, seed, args.seconds, False, log=log,
-                        variants=kinds if i < args.variants else ())
+                        variants=others if i < args.variants else ())
         print(json.dumps({
             "seed": seed, "correct": r["correct"],
             "numbers": {k: c["value"] for k, c in r["checks"].items()},

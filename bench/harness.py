@@ -1,32 +1,60 @@
 """One run of one cell: set-up, the timed window, the comparison, the
 metrics.  Everything a cell needs is found by name from
-``BENCHMARK.json``: its configuration file, ``bench/traffic/<traffic>.json``,
-``bench/limits/<cell>.json`` and one reader per metric,
-``bench/metrics/<metric>.py``.
+``BENCHMARK.json``, under the checkout's root: its configuration file,
+``bench/traffic/<traffic>.json``, ``bench/limits/<cell>.json``, its kind
+``bench/kinds/<kind>.py`` and one reader per metric,
+``bench/metrics/<metric>.py`` (kinds and readers not under the root are
+taken from this benchmark's own).
+
+A configuration names its kind (``"kind"``, default ``rff_coded``).  The
+kind is what differs between configurations; a module defines:
+
+``make_inputs(cfg, traffic, seed) -> inputs``
+    the run's inputs made from the benchmark's seed (any whole number),
+    in bulk or on the device; the harness waits for them and counts them
+    in set-up.
+``System(cfg, traffic, inputs)``
+    the deployment built through the program's entry point, with
+    ``step()`` (one call, ending in host values; returns the rounds or
+    steps it played), ``skipped()`` (rounds the program skipped so far),
+    ``snapshot()`` (host values of the iterate that the comparison
+    needs: never the whole iterate where that is large, but such as
+    norms per leaf and a loss) and ``handoff()`` (host values that the
+    tail hands the reference).  The harness drops the system, and
+    collects garbage, before `check` runs, so a reference can take the
+    device's memory and run in blocks.
+``check(cfg, traffic, inputs, ran, limits, variants)``
+    ``(correct, checks, {variant: numbers}, ctx)``: ``ran`` holds the
+    snapshots after the first calls (``warm``) and at the window's close
+    (``window_end``), the window's call count (``calls``) and the
+    handoff (``tail``); ``checks`` is ``{name: {"value", "limit"}}``;
+    each of `variants` is compared with the reference in the program's
+    place; ``ctx`` holds the kind's own entries for the metric readers,
+    such as the work the window needed.
+``VARIANTS``
+    the names `check` takes in `variants` (``bench/calibrate.py``).
 
 Run order:
 
-1. set-up (``bench/setup``): inputs from the seed, the deployment built
-   through the program's entry point, then the first ``steps_compared``
-   run_block calls (``bench/warmup``: they compile, and their iterates
-   are what the reference follows);
-2. the window: back-to-back run_block calls (``bench/run_block``), each
-   ending in host values, until ``--seconds`` have passed; the call that
-   straddles the end counts, with its time;
+1. set-up (``bench/setup``): the inputs, the system, then the first
+   ``steps_compared`` calls (``bench/warmup``: they compile, and their
+   snapshots are what the reference follows);
+2. the window: back-to-back calls (``bench/run_block``) until
+   ``--seconds`` have passed; the call that straddles the end counts,
+   with its time;
 3. the device's memory peak is read; the program plays
    ``steps_compared`` calls more, untimed, from the window's end (the
-   tail); its answers are taken and its state is freed;
-4. the reference replays set-up and the compared calls, draws the
-   window's calls without playing them (the rounds' draws give the work
-   the window needed, ``bench/workcount.py``), then plays the tail from
-   the program's iterate at the window's end; the numbers are held
-   against their limits (``bench/compare.py``).
+   tail); its handoff is taken and the system is freed;
+4. the kind's `check`.
 
-With ``trace`` the window runs under the profiler and the per-layer
-metrics are reported; otherwise the end-to-end ones.
+With ``trace`` the window runs under the profiler, with the program's
+spans and counters on (``ctx["window_spans"]``,
+``ctx["window_counters"]``), and the per-layer metrics are reported;
+otherwise the spans stay off and the end-to-end metrics are reported.
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import importlib.util
 import json
@@ -38,23 +66,12 @@ import time
 
 import numpy as np
 
-import compare
-import generate
 import trace_reduce
-import workcount
-from reference import Arith, Data, Reference
-from system import System
+from workcount import peaks
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
-
-
-#: reference variants that can be put in the program's place, compared
-#: exactly as the program is (``bench/calibrate.py``, tests)
-VARIANTS = {"reference": {}, "control": {"control": True},
-            "bf16": {"operands": "bf16"},
-            "half_batch": {"fault": "half_batch"},
-            "cursor_drift": {"fault": "cursor_drift"}}
+DEFAULT_KIND = "rff_coded"
 
 
 class NoAccelerator(RuntimeError):
@@ -66,14 +83,21 @@ def _read_json(path: str) -> dict:
         return json.load(fh)
 
 
-def metric_reader(name: str):
-    """The `read(ctx)` of ``bench/metrics/<name>.py``."""
-    path = os.path.join(BENCH, "metrics", f"{name}.py")
+def _module(sub: str, name: str, root: str):
+    """``bench/<sub>/<name>.py`` under `root`, else this benchmark's."""
+    path = os.path.join(root, "bench", sub, f"{name}.py")
+    if not os.path.exists(path):
+        path = os.path.join(BENCH, sub, f"{name}.py")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
+        f"bench_{sub}_" + name.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: str = ROOT):
+    """The `read(ctx)` of ``bench/metrics/<name>.py``."""
+    return _module("metrics", name, root).read
 
 
 def load_cell(name: str, root: str = ROOT) -> dict:
@@ -89,16 +113,19 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [name] if m["moves"]
                                   in reported else [])]
+    config = _read_json(os.path.join(root, centry["file"]))
+    here = os.path.join(root, "bench")
     return {
         "name": name,
         "chips": wl["chips"],
-        "config": _read_json(os.path.join(root, centry["file"])),
-        "traffic": _read_json(os.path.join(BENCH, "traffic",
+        "config": config,
+        "traffic": _read_json(os.path.join(here, "traffic",
                                            f"{wl['traffic']}.json")),
-        "limits": _read_json(os.path.join(BENCH, "limits", f"{name}.json")),
-        "end_to_end": [(m["name"], m["unit"], metric_reader(m["name"]))
+        "limits": _read_json(os.path.join(here, "limits", f"{name}.json")),
+        "kind": _module("kinds", config.get("kind", DEFAULT_KIND), root),
+        "end_to_end": [(m["name"], m["unit"], metric_reader(m["name"], root))
                        for m in e2e],
-        "per_layer": [(m["name"], m["unit"], metric_reader(m["name"]))
+        "per_layer": [(m["name"], m["unit"], metric_reader(m["name"], root))
                       for m in per_layer],
     }
 
@@ -116,13 +143,13 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
         variants: tuple = (), log=print) -> dict:
     """One run of cell `name`; returns the result object (the contract's
     last stdout line).  `overrides` patches the configuration (tests run
-    cells at a size the CPU holds); each of `variants` (names in
-    `VARIANTS`) is also compared with the reference in the program's
-    place, under the result's ``variants`` key."""
+    cells at a size the CPU holds); each of `variants` (names in the
+    kind's ``VARIANTS``) is also compared with the reference in the
+    program's place, under the result's ``variants`` key."""
     t0 = time.perf_counter() if start is None else start
     cell = load_cell(name, root)
     cfg = _merge(cell["config"], overrides or {})
-    traffic = cell["traffic"]
+    traffic, kind = cell["traffic"], cell["kind"]
 
     import jax
     devs = jax.devices()
@@ -133,30 +160,28 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
         raise NoAccelerator(
             f"cell {name!r} needs {cell['chips']} accelerator chip(s); JAX "
             f"found {len(devs)} {dev.platform} device(s)")
-    peak = None if dev.platform == "cpu" else workcount.peaks(dev.device_kind)
+    peak = None if dev.platform == "cpu" else peaks(dev.device_kind)
 
     from repro.obs import spans as obs_spans
 
-    fl_seed, data_seed = generate.seeds(seed)
-    hier = generate.hierarchical(cfg)
     steps = int(traffic["steps_compared"])
     with jax.profiler.TraceAnnotation("bench/setup"):
-        x, y = generate.make_data(cfg, data_seed, fl_seed, host=hier)
-        jax.block_until_ready((x, y))
+        inputs = kind.make_inputs(cfg, traffic, seed)
+        jax.block_until_ready(inputs)
         data_s = time.perf_counter() - t0
         span_totals = {}
         if trace:
             with obs_spans.collecting() as sp:
-                system = System(cfg, traffic, fl_seed, x, y)
+                system = kind.System(cfg, traffic, inputs)
                 span_totals = sp.totals()
         else:
-            system = System(cfg, traffic, fl_seed, x, y)
+            system = kind.System(cfg, traffic, inputs)
         tw = time.perf_counter()
-        thetas_p = []
+        warm = []
         with jax.profiler.TraceAnnotation("bench/warmup"):
             for _ in range(steps):
                 system.step()
-                thetas_p.append(system.theta())
+                warm.append(system.snapshot())
         warmup_s = time.perf_counter() - tw
     setup_s = time.perf_counter() - t0
     log(f"setup_s={setup_s:.3f} (data {data_s:.3f}, warm-up {warmup_s:.3f})")
@@ -168,15 +193,20 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
     skipped0 = system.skipped()
     blocks, rounds = [], 0
-    w0 = time.perf_counter()
-    while True:
-        b0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("bench/run_block"):
-            rounds += system.step()
-        b1 = time.perf_counter()
-        blocks.append(b1 - b0)
-        if b1 - w0 >= seconds:
-            break
+    window_spans, window_counters = {}, {}
+    with obs_spans.collecting() if trace else contextlib.nullcontext():
+        w0 = time.perf_counter()
+        while True:
+            b0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("bench/run_block"):
+                rounds += system.step()
+            b1 = time.perf_counter()
+            blocks.append(b1 - b0)
+            if b1 - w0 >= seconds:
+                break
+        if trace:
+            window_spans = obs_spans.totals()
+            window_counters = obs_spans.counters()
     window_s = b1 - w0
     if trace:
         jax.profiler.stop_trace()
@@ -190,48 +220,29 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     mem = max((s or {}).get("peak_bytes_in_use", 0) for s in stats) \
         if any(stats) else None
     failed = system.skipped() - skipped0
-    theta_w = system.theta()
+    window_end = system.snapshot()
     for _ in range(steps):
         system.step()
-    ar = Arith()
-    probe = compare.probe(cfg["q"])
-    sp = compare.side(compare.probed(system.answers(), ar.mm, probe),
-                      thetas_p, system.theta(), system.returned())
+    ran = {"warm": warm, "window_end": window_end, "calls": len(blocks),
+           "tail": system.handoff()}
     del system
     gc.collect()
 
     tr0 = time.perf_counter()
-    net = generate.network(cfg, fl_seed)
-    data = Data(x, y)
-
-    def replay(**variant):
-        r = Reference(cfg, traffic, fl_seed, net, data, **variant)
-        setup = compare.probed(r.shards, ar.mm, probe)
-        thetas = r.run(steps)
-        back = r.skip(len(blocks))
-        tail = r.run(steps, theta_w)[-1]
-        return r, compare.side(setup, thetas, tail,
-                               np.concatenate(r.returned)), back
-
-    ref, sr, back = replay()
-    correct, checks = compare.judge(
-        compare.numbers(sp, sr, theta_w, ref.losses), cell["limits"])
-    rows = workcount.needed_rows(back, ref.loads,
-                                 sum(sh["u"] for sh in ref.shards))
-    others = {kind: compare.numbers(replay(**VARIANTS[kind])[1], sr,
-                                    theta_w, ref.losses)
-              for kind in variants}
+    correct, checks, others, kind_ctx = kind.check(
+        cfg, traffic, inputs, ran, cell["limits"], variants)
     log(f"reference_s={time.perf_counter() - tr0:.3f}")
 
     ctx = {"setup_s": setup_s, "warmup_s": warmup_s, "window_s": window_s,
            "rounds": rounds, "blocks_s": blocks, "spans": span_totals,
-           "rows": rows, "q": cfg["q"], "c": cfg["classes"], "peak": peak,
-           "trace": None}
+           "window_spans": window_spans, "window_counters": window_counters,
+           "peak": peak, "trace": None, **kind_ctx}
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devs), "memory_peak_bytes": mem}
     result = {"correct": correct, "attempted": rounds, "failed": failed}
     if trace:
-        red = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir))
+        red = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir),
+                                  window_spans)
         shutil.rmtree(trace_dir, ignore_errors=True)
         ctx["trace"] = red
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])

@@ -1,16 +1,18 @@
-"""The idle split by program span (``bench/span_profile.py``) and the
-readers of the program's spans and counters."""
+"""The idle split by program span (``bench/trace_reduce.py``) and the
+readers of the program's spans and counters in the traced window."""
 import os
 
 import numpy as np
 import pytest
 
 import harness
-import span_profile
 import trace_reduce
-from span_profile import OUTSIDE, split_idle
+from trace_reduce import OUTSIDE, split_idle
 
 TRACE = os.path.join(os.path.dirname(__file__), "data", "small.xplane.pb")
+#: the readers of the window's spans and counters, and their cell
+READERS = ("prepare_idle_ms", "fetch_idle_ms", "rows_per_needed",
+           "rows_per_needed.hier", "h2d_gb_per_round.hier")
 
 
 def _sums_to_gaps(split, gaps):
@@ -44,7 +46,7 @@ def test_split_by_overlap(case):
 
 def test_innermost_pieces_are_disjoint():
     spans = [(0, 10, "p"), (2, 4, "c1"), (4, 8, "c2"), (5, 6, "g")]
-    assert span_profile.innermost(spans) == [
+    assert trace_reduce.innermost(spans) == [
         (0, 2, "p"), (2, 4, "c1"), (4, 5, "c2"), (5, 6, "g"), (6, 8, "c2"),
         (8, 10, "p")]
 
@@ -52,8 +54,8 @@ def test_innermost_pieces_are_disjoint():
 @pytest.mark.parametrize("names", [[], ["bench/host"],
                                    ["bench/host", "bench/run_block"]])
 def test_split_of_recorded_trace_sums_to_idle(names):
-    red = trace_reduce.reduce(TRACE)
-    split = span_profile.idle_by_span(TRACE, names)
+    red = trace_reduce.reduce(TRACE, names)
+    split = red["idle_by_span"]
     assert set(split) == set(names) | {OUTSIDE}
     idle = red["window_s"] - red["busy_s"]
     assert sum(split.values()) == pytest.approx(idle, rel=1e-6)
@@ -65,15 +67,15 @@ def test_split_of_recorded_trace_sums_to_idle(names):
 
 def _ctx(**over):
     ctx = {"rounds": 20, "rows": 1000, "blocks_s": [0.01, 0.01],
-           "spans": {}, "trace": None, "window_counters": {}}
+           "spans": {}, "trace": None, "window_spans": {},
+           "window_counters": {}}
     ctx.update(over)
     return ctx
 
 
 def test_readers_give_none_without_their_input():
-    for read in span_profile.METRICS.values():
-        assert read(_ctx()) is None
-    assert harness.metric_reader("encode_s")(_ctx()) is None
+    for name in READERS + ("encode_s",):
+        assert harness.metric_reader(name)(_ctx()) is None, name
 
 
 def test_readers_on_synthetic_ctx():
@@ -84,8 +86,9 @@ def test_readers_on_synthetic_ctx():
                                                    "total": 6.62e10}},
                spans={"encode/parity": {"count": 1, "total_s": 0.25,
                                         "min_s": 0.25, "max_s": 0.25}})
-    got = {k: read(ctx) for k, read in span_profile.METRICS.items()}
+    got = {k: harness.metric_reader(k)(ctx) for k in READERS}
     assert got == pytest.approx({"prepare_idle_ms": 2.0, "fetch_idle_ms": 3.0,
                                  "rows_per_needed": 3.1,
-                                 "h2d_gb_per_round": 3.31})
+                                 "rows_per_needed.hier": 3.1,
+                                 "h2d_gb_per_round.hier": 3.31})
     assert harness.metric_reader("encode_s")(ctx) == 0.25
