@@ -1,9 +1,11 @@
 """End-to-end driver: federated training of a transformer LM with the
-paper's deadline-based aggregation (DESIGN.md §4 generalization).
+paper's deadline-based aggregation, through ``repro.api.build_experiment``
+with a model spec (``ExperimentSpec.model``, `repro.core.lm_round`).
 
-Each simulated MEC client owns a data shard; per round, client round-trip
-delays are sampled from the paper's §II-B models, the load allocator picks
-the deadline t*, stragglers are dropped, and surviving gradients are
+Each simulated MEC client holds one token sequence; per round, client
+round-trip delays are sampled from the paper's Sec. II-B models, the coded
+scheme's allocation sets the deadline t* and each client's load (a prefix
+of its sequence), stragglers are dropped, and surviving gradients are
 reweighted by 1/P(T_j <= t*) so the aggregate stays unbiased.
 
 Default is a ~20M-param qwen3-family model for a quick CPU run; use
@@ -37,7 +39,6 @@ def main():
     ap.add_argument("--params", default="20m", choices=["20m", "100m"])
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--clients", type=int, default=8)
-    ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--seq", type=int, default=128)
     args = ap.parse_args()
     cfg = model_cfg(args.params)
@@ -48,7 +49,7 @@ def main():
           f"{args.clients} federated clients, deadline aggregation")
     t0 = time.time()
     _, losses, sim_wall = train(
-        cfg, steps=args.steps, batch=args.batch, seq=args.seq,
+        cfg, steps=args.steps, seq=args.seq,
         federated=True, fl_cfg=FLConfig(n_clients=args.clients),
         log_every=max(1, args.steps // 10))
     print(f"loss {losses[0]:.3f} -> {losses[-1]:.3f} over {args.steps} steps "

@@ -79,7 +79,9 @@ def build_experiment(spec: "ExperimentSpec | dict", x_stack=None,
     x_stack: (n, l, q) RFF-embedded client features — or, with
     ``spec.fused_embed=True``, the RAW (n, l, d) features (the embedding
     then happens inside the per-round gradient kernel, parameterized by
-    ``spec.rff``); y_stack: (n, l, c) targets.  `nodes` / `rng` override the delay network and the host RNG
+    ``spec.rff``); y_stack: (n, l, c) targets.  With ``spec.model`` (a
+    language model, `repro.core.lm_round`) x_stack is the clients' token
+    ids, (n, S + 1), and y_stack is None.  `nodes` / `rng` override the delay network and the host RNG
     (both default to the spec's seeds, so equal specs reproduce equal
     deployments).  `mesh` accepts a concrete 1-D "clients"
     `jax.sharding.Mesh` (not serializable, hence not a spec field) or a

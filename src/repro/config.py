@@ -18,6 +18,36 @@ class MoEConfig:
     capacity_factor: float = 1.25
     every_n_layers: int = 1         # apply MoE FFN every n-th layer (1 = all)
     aux_loss_weight: float = 0.01
+    # DeepSeek-V2's keys (arXiv:2405.04434, its config.json): whether the
+    # top-k gates are renormalised to sum to 1, and the factor on them
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # dropless (DeepSeek-V2): every routed token reaches its experts,
+    # computed as grouped matrix products over the tokens sorted by
+    # expert (no capacity buffer), with DeepSeek's per-sequence balance
+    # loss (its config's `seq_aux`) in place of GShard's
+    dropless: bool = False
+    # (first, count): the routed experts this device holds, as one chip of
+    # an expert-parallel group would; the router still scores all
+    # `num_experts`.  None holds them all.
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if not self.dropless:
+                raise ValueError("experts_held needs the dropless layer")
+            if not (0 <= first and 0 < count
+                    and first + count <= self.num_experts):
+                raise ValueError(f"experts_held={self.experts_held} is not "
+                                 f"a range of the {self.num_experts} "
+                                 "experts")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        return tuple(self.experts_held) if self.experts_held is not None \
+            else (0, self.num_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +57,18 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling as DeepSeek-V2 applies it (``rope_scaling`` of
+    its config.json, type "yarn")."""
+    factor: float = 40.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    original_max_position_embeddings: int = 4096
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +108,7 @@ class ModelConfig:
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     rwkv: Optional[RWKVConfig] = None
+    yarn: Optional[YarnConfig] = None   # YaRN frequencies on the rope dims
     # enc-dec (whisper): number of encoder layers; encoder input is a stub
     # of precomputed frame embeddings (audio carve-out).
     n_encoder_layers: int = 0
@@ -170,6 +213,41 @@ class RFFConfig:
     seed: int = 1234
 
 
+@dataclasses.dataclass(frozen=True)
+class LMSpec:
+    """A language model trained through the federated round in place of
+    the RFF regression (``ExperimentSpec.model``).
+
+    `model` is the model as this device holds it: its
+    ``moe.experts_held`` share of the routed experts and, where the
+    vocabulary is sliced, its ``vocab`` rows.  Client data is one sequence
+    of token ids per client, (n, S + 1); a point is a next-token target,
+    and client j's load is the prefix of its first l*_j targets.  Weights
+    come from `init_seed`.  The round's gradient is taken over
+    `micro_batch` clients at a time (0: all in one backward pass), then
+    clipped and applied by AdamW (`repro.core.lm_round`;
+    ``TrainConfig.learning_rate`` and ``weight_decay``).
+    """
+    model: ModelConfig
+    init_seed: int = 0
+    micro_batch: int = 0
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "LMSpec":
+        d = dict(d)
+        m = dict(d["model"])
+        for key, typ in (("moe", MoEConfig), ("mla", MLAConfig),
+                         ("ssm", SSMConfig), ("rwkv", RWKVConfig),
+                         ("yarn", YarnConfig)):
+            if isinstance(m.get(key), dict):
+                sub = dict(m[key])
+                if sub.get("experts_held") is not None:
+                    sub["experts_held"] = tuple(sub["experts_held"])
+                m[key] = typ(**sub)
+        d["model"] = ModelConfig(**m)
+        return cls(**d)
+
+
 _ENGINES = ("batched", "legacy")
 _KERNEL_BACKENDS = ("xla", "pallas")
 _ALLOC_BACKENDS = ("auto", "scalar", "vectorized")
@@ -272,6 +350,10 @@ class ExperimentSpec:
     # only routes to HierExperiment when either departs from identity.
     hier_shards: int = 1
     sample_fraction: float = 1.0
+    # a language model in place of the RFF regression (`LMSpec`): the
+    # round trains it on token sequences, under the coded scheme's
+    # deadline and loads with no parity set (parity coding is linear only)
+    model: Optional[LMSpec] = None
 
     def __post_init__(self):
         if self.engine not in _ENGINES:
@@ -426,6 +508,32 @@ class ExperimentSpec:
                     f"the hierarchical tier ({hier}) shards clients over "
                     "edge aggregators, not a device mesh; drop mesh")
 
+        if self.model is not None:
+            if not isinstance(self.model, LMSpec):
+                raise ValueError(f"model must be an LMSpec, got "
+                                 f"{type(self.model).__name__}")
+            unsupported = [name for name, on in (
+                ("engine='legacy'", self.engine == "legacy"),
+                ("the hierarchical tier", self.hier_active),
+                ("channel_profile/channel_params",
+                 self.channel_profile is not None
+                 or bool(self.channel_params)),
+                ("fault_profile/fault_params",
+                 self.fault_profile is not None or bool(self.fault_params)),
+                ("adapt_every", self.adapt_every > 0),
+                ("mesh", self.mesh is not None),
+                ("fused_embed", self.fused_embed),
+                ("secure_aggregation", self.secure_aggregation),
+                ("rff", self.rff is not None)) if on]
+            if unsupported:
+                raise ValueError(
+                    "a model spec (ExperimentSpec.model) trains through the "
+                    "flat coded-deadline round only; not combined with "
+                    + ", ".join(unsupported))
+            if self.model.micro_batch < 0:
+                raise ValueError(f"model.micro_batch must be >= 0, got "
+                                 f"{self.model.micro_batch}")
+
     @property
     def hier_active(self) -> bool:
         """True when the spec departs from the flat engine's identity
@@ -512,6 +620,8 @@ class ExperimentSpec:
                     if tup_field in val and val[tup_field] is not None:
                         val[tup_field] = tuple(val[tup_field])
                 d[key] = typ(**val)
+        if isinstance(d.get("model"), dict):
+            d["model"] = LMSpec.from_dict(d["model"])
         valid = {f.name for f in dataclasses.fields(cls)}
         unknown = set(d) - valid
         if unknown:

@@ -129,7 +129,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.checkpoint import io as ckpt_io
 from repro.config import ExperimentSpec
-from repro.core import aggregation, rff as rff_mod, schemes
+from repro.core import aggregation, lm_round, rff as rff_mod, schemes
 from repro.kernels import ops as kernel_ops
 from repro.kernels.backend import resolve_interpret
 from repro.core.delay_model import (mec_network, packet_bits,
@@ -572,14 +572,19 @@ def _unpack_inputs(words, layout) -> list:
     return leaves
 
 
-def _pack_outputs(per_round, lr_scale) -> jnp.ndarray:
-    """A block's host-bound outputs as one (4K + 1,) int32 vector, for
-    one fetch: the per-round t_round (f32 bits), n_ret, n_masked and
-    skipped, then the carry's lr_scale (f32 bits)."""
+def _pack_outputs(per_round, lr_scale, extra=()) -> jnp.ndarray:
+    """A block's host-bound outputs as one (4K + 1 + EK,) int32 vector,
+    for one fetch: the per-round t_round (f32 bits), n_ret, n_masked and
+    skipped, then the carry's lr_scale (f32 bits), then the E per-round
+    `extra` outputs of the model round (`lm_round.EXTRAS`; floats as
+    their bits)."""
     t_round, n_ret, n_masked, skipped = per_round
     bits = jax.lax.bitcast_convert_type
+    extra = [bits(a, jnp.int32) if a.dtype == jnp.float32 else a
+             for a in extra]
     return jnp.concatenate([bits(t_round, jnp.int32), n_ret, n_masked,
-                            skipped, bits(lr_scale, jnp.int32)[None]])
+                            skipped, bits(lr_scale, jnp.int32)[None]]
+                           + extra)
 
 
 def _unpack_outputs(words: np.ndarray, K: int) -> tuple:
@@ -753,12 +758,26 @@ class Experiment:
         self.last_schedule = None     # AdaptiveSchedule of the latest run
         self.fl = fl_cfg
         self.train = spec.train
-        self.x = jnp.asarray(x_stack)
-        self.y = jnp.asarray(y_stack)
+        self.fused_embed = spec.fused_embed
+        # a model spec (repro.core.lm_round): x_stack is the clients' token
+        # ids (n, S + 1), one sequence each; a point is a next-token target
+        self.lm = spec.model
+        if self.lm is not None:
+            if y_stack is not None:
+                raise ValueError("a model spec takes the clients' token ids "
+                                 "(n, S + 1) alone; y_stack must be None")
+            self.x = jnp.asarray(x_stack, jnp.int32)
+            self.y = None
+            self.n, self.l = self.x.shape[0], self.x.shape[1] - 1
+            self.q = self.c = self.d = None
+            self.omega = self.delta = None
+        else:
+            self.x = jnp.asarray(x_stack)
+            self.y = jnp.asarray(y_stack)
+            self.c = self.y.shape[-1]
         # fused_embed: x_stack is RAW (n, l, d); q comes from the RFF
         # config and the shared-seed (Omega, delta) are derived here so
         # the in-kernel embed matches rff.rff_transform exactly
-        self.fused_embed = spec.fused_embed
         if self.fused_embed:
             if self.adaptive:
                 raise NotImplementedError(
@@ -772,18 +791,23 @@ class Experiment:
             self.n, self.l, self.d = self.x.shape
             self.q = spec.rff.q
             self.omega, self.delta = rff_mod.rff_params(spec.rff, self.d)
-        else:
+        elif self.lm is None:
             self.n, self.l, self.q = self.x.shape
             self.d = None
             self.omega = self.delta = None
-        self.c = self.y.shape[-1]
         self.m = self.n * self.l
         self.steps_per_epoch = spec.steps_per_epoch
         self.rng = rng or np.random.default_rng(fl_cfg.seed + 17)
 
         # --- delay network (tau scaled to the actual gradient/model packet)
-        base_nodes = nodes or mec_network(fl_cfg, d_scalars_per_point=self.q * self.c)
-        payload = packet_bits(fl_cfg, self.q * self.c)    # model == gradient size
+        # a model's packet is its parameters, and a token costs a forward
+        # and a backward pass over them: 3 MACs per parameter
+        scalars = (self.q * self.c if self.lm is None
+                   else lm_round.param_count(self.lm.model))
+        per_point = scalars if self.lm is None else 3 * scalars
+        base_nodes = nodes or mec_network(fl_cfg,
+                                          d_scalars_per_point=per_point)
+        payload = packet_bits(fl_cfg, scalars)    # model == gradient size
         self.nodes = [scale_tau(nd, payload) for nd in base_nodes[:self.n]]
 
         self.t_star = None
@@ -796,9 +820,12 @@ class Experiment:
         # telemetry capture (repro.obs): per-block delay/plan references
         # kept only while spans are enabled, feeding `attribution()`
         self._attr_blocks: "list[dict]" = []
+        if self.lm is not None:
+            lm_round.validate(self)
         with obs_spans.span("setup/experiment"):
             self.scheme_obj.setup(self)
-        self.privacy_eps = self.scheme_obj.privacy_budget(self)
+        self.privacy_eps = (None if self.lm is not None
+                            else self.scheme_obj.privacy_budget(self))
         self._consts = None     # built lazily on first run/run_multi
 
     @staticmethod
@@ -868,6 +895,8 @@ class Experiment:
         (repro.launch.sweep).  With a mesh, the client axis is additionally
         zero-row padded to a multiple of the mesh size.
         """
+        if self.lm is not None:
+            return lm_round.consts(self)
         gx, gy, gmask, tail = self.scheme_obj.grad_tensors(self, l_target)
         if self.mesh is not None:
             rows = -(-gx.shape[0] // self.mesh.size) * self.mesh.size
@@ -993,7 +1022,14 @@ class Experiment:
         cache_key = (self.scheme, collect_theta, layout)
         fn = self._scan_cache.get(cache_key)
         if fn is None:
-            step = build_step(self.step_static(collect_theta))
+            if self.lm is not None:
+                # the model's iterate is donated: a block consumes its
+                # input state's parameter and optimizer buffers
+                step, n_extra, donate = (lm_round.build_step(self),
+                                         len(lm_round.EXTRAS), (1,))
+            else:
+                step, n_extra, donate = (
+                    build_step(self.step_static(collect_theta)), 0, ())
 
             def block(consts, thetas, words):
                 *xs, lr_scale = _unpack_inputs(words, layout)
@@ -1001,10 +1037,11 @@ class Experiment:
                 carry, per_round = jax.lax.scan(
                     lambda c, inp: step(consts, c, inp), carry0, tuple(xs))
                 return ((carry[0],) + carry[2:],
-                        _pack_outputs(per_round[:4], carry[1]),
-                        per_round[4:])
+                        _pack_outputs(per_round[:4], carry[1],
+                                      per_round[4:4 + n_extra]),
+                        per_round[4 + n_extra:])
 
-            fn = self._timed_scan(jax.jit(block))
+            fn = self._timed_scan(jax.jit(block, donate_argnums=donate))
             self._scan_cache[cache_key] = fn
         return fn
 
@@ -1086,7 +1123,7 @@ class Experiment:
     # ------------------------------------------------- block-structured runs
     def init_state(self, iterations: int, *,
                    n_realizations: Optional[int] = None,
-                   collect: bool = False) -> RunState:
+                   collect: bool = False, params=None) -> RunState:
         """Fresh `RunState` for a run of `iterations` rounds.
 
         ``n_realizations=None`` starts a "single" run; otherwise a
@@ -1097,8 +1134,13 @@ class Experiment:
         state is seeded from this experiment's live RNG and the run's
         trace streams are reserved here, so runs launched back to back
         consume disjoint randomness exactly like the pre-RunState
-        engine.
+        engine.  A model spec's run starts from `params` where given (the
+        caller's weights, `repro.core.lm_round.init_theta`), else from
+        weights made from ``spec.model.init_seed``.
         """
+        if params is not None and self.lm is None:
+            raise ValueError("params are a model spec's initial weights; "
+                             "this experiment trains no model")
         iterations = int(iterations)
         if iterations < 1:
             raise ValueError(f"iterations={iterations} must be >= 1")
@@ -1129,8 +1171,17 @@ class Experiment:
                 # estimator/controls are block-local (a block IS one
                 # whole realization), so they never live in the state
                 trace_call = self._reserve_trace_streams(R)
+        lm_log = None
+        if self.lm is not None:
+            if mode != "single" or collect:
+                raise ValueError("a model spec runs single trajectories "
+                                 "(no run_multi, no eval_fn)")
+            lm_log = {k: np.zeros(0, np.float64 if k == "loss" else np.int64)
+                      for k in lm_round.EXTRAS}
         if mode == "single":
-            theta = jnp.zeros((self.q, self.c), jnp.float32)
+            theta = (lm_round.init_theta(self.lm, params)
+                     if self.lm is not None
+                     else jnp.zeros((self.q, self.c), jnp.float32))
             t_rounds = np.zeros(0, np.float64)
             n_ret = np.zeros(0, np.int32)
             lr_scale = 1.0
@@ -1171,14 +1222,15 @@ class Experiment:
             t_rounds=t_rounds, n_ret=n_ret, losses=losses, accs=accs,
             sched=sched, lr_scale=lr_scale, n_masked=n_masked,
             skipped=skipped, theta_prev=theta_prev,
-            fault_rng_state=fault_rng_state)
+            fault_rng_state=fault_rng_state, lm_log=lm_log)
 
     def run_block(self, state: RunState, n_rounds: Optional[int] = None, *,
                   eval_fn: Optional[Callable] = None,
                   eval_every: int = 10) -> RunState:
         """Advance a run by one block and return the NEW `RunState` (the
         input is never mutated, so replaying a block from a saved state
-        is always safe).
+        is always safe; a model spec's block consumes the input state's
+        parameter and optimizer buffers, `repro.core.lm_round`).
 
         ``n_rounds`` defaults to ``spec.checkpoint_every``, or the whole
         remaining horizon when that is 0.  "multi_channel" runs advance
@@ -1228,7 +1280,7 @@ class Experiment:
         r0 = state.rounds_done
         with obs_spans.span("block/prepare"):
             consts = self._get_consts()
-            if obs_spans.enabled():
+            if obs_spans.enabled() and self.lm is None:
                 obs_spans.count("round/rows", K * _round_rows(consts))
             trace_new = state.trace
             est_new, controls_new = state.est, state.controls
@@ -1287,8 +1339,17 @@ class Experiment:
         thetas, packed, collected = scan_fn(consts, thetas, words)
         packed.copy_to_host_async()
         with obs_spans.span("block/fetch"):
+            out = np.asarray(packed)
             (t_rounds_b, n_ret_b, n_masked_b, skipped_b,
-             lr_scale) = _unpack_outputs(np.asarray(packed), K)
+             lr_scale) = _unpack_outputs(out, K)
+        lm_log = state.lm_log
+        if self.lm is not None:
+            ext = lm_round.unpack_extras(out[4 * K + 1:], K)
+            obs_spans.count("round/tokens", K * self.n * self.l)
+            obs_spans.count("moe/routed", int(ext["routed"].sum()))
+            obs_spans.count("moe/expert_rows", int(ext["rows"].sum()))
+            lm_log = {k: np.concatenate([state.lm_log[k], ext[k]])
+                      for k in lm_round.EXTRAS}
         losses_new, accs_new = state.losses, state.accs
         if state.collect:
             thetas_r = collected[0]
@@ -1314,7 +1375,7 @@ class Experiment:
                 n_masked=np.concatenate([state.n_masked, n_masked_b]),
                 skipped=np.concatenate([state.skipped, skipped_b]),
                 theta_prev=(thetas[1] if self.stale_faults else None),
-                fault_rng_state=fault_rng_new)
+                fault_rng_state=fault_rng_new, lm_log=lm_log)
 
     def _block_multi(self, state: RunState, rng, K: int, lrs) -> RunState:
         """K rounds of ALL stationary realizations in one vmapped scan
@@ -1414,6 +1475,10 @@ class Experiment:
     def save_state(self, path: str, state: RunState) -> str:
         """Checkpoint `state` atomically (`repro.checkpoint.io`),
         embedding this experiment's `ExperimentSpec` as JSON provenance."""
+        if self.lm is not None:
+            raise NotImplementedError(
+                "checkpoints of a model spec's run state (a parameter "
+                "pytree and AdamW state) are not supported yet")
         arrays, meta = pack_state(state)
         meta["spec"] = self.spec.to_dict()
         with obs_spans.span("checkpoint/save"):
@@ -1479,6 +1544,8 @@ class Experiment:
         have_guards = state.n_masked is not None
         for it in range(state.iterations):
             loss = float(state.losses[it]) if state.collect else float("nan")
+            if state.lm_log is not None:
+                loss = float(state.lm_log["loss"][it])
             acc = float(state.accs[it]) if state.collect else float("nan")
             history.append(RoundLog(
                 it, float(wall[it]), int(state.n_ret[it]), loss, acc,

@@ -98,6 +98,9 @@ class RunState:
     fault_rng_state: Optional[dict] = None  # fault-stream RNG (PCG64)
     # --- hierarchical tier state (mode "hier") -----------------------
     sample_rng_state: Optional[dict] = None  # client-sampling-stream RNG
+    # --- model round (repro.core.lm_round): per-round loss, returned
+    # mask bits, routed assignments and expert rows (not checkpointed)
+    lm_log: Optional[dict] = None
 
     def __post_init__(self):
         if self.mode not in _MODES:

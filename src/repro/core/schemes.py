@@ -184,6 +184,11 @@ class CodedScheme(Scheme):
         exp.p_return = np.array([
             nd.cdf(exp.t_star, float(ld)) if ld > 0 else 0.0
             for nd, ld in zip(exp.nodes, exp.loads)])
+        if exp.lm is not None:
+            # a model round (repro.core.lm_round) uses the deadline and
+            # loads alone: each client's load is the prefix of its
+            # sequence, and no parity set is built (parity is linear only)
+            return
         # Processed-subset sampling v2 (vectorized): one `rng.permuted` draw
         # over an (n, l) index matrix replaces the per-client
         # `rng.permutation` loop.  This consumes the numpy RNG stream

@@ -4,13 +4,16 @@ Two modes:
   * plain      — single-host (reduced-config) LM training on synthetic
                  tokens; used by smoke tests and examples.
   * federated  — CodedFedL-style deadline aggregation generalized to deep
-                 models: each data-parallel shard is a simulated MEC client
-                 with the paper's delay model; gradients that miss the
-                 optimized deadline t* are dropped and the survivors are
-                 reweighted by 1/P(T_j <= t*) (unbiasedness logic of
-                 §III-E applied at the gradient-aggregation layer — see
-                 DESIGN.md §4 for why the parity-coded gradient itself is
-                 linear-model-only).
+                 models, through the system's normal path:
+                 ``repro.api.build_experiment`` with a model spec
+                 (``ExperimentSpec.model``).  Each client is a simulated MEC
+                 client with the paper's delay model holding one token
+                 sequence; the coded scheme's allocation sets the deadline
+                 t* and each client's load (a prefix of its sequence);
+                 gradients that miss t* are dropped and the survivors are
+                 reweighted by 1/P(T_j <= t*) inside the compiled round
+                 (`repro.core.lm_round`; parity coding itself is
+                 linear-model-only, paper Sec. III).
 """
 from __future__ import annotations
 
@@ -21,10 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import FLConfig
+from repro.config import ExperimentSpec, FLConfig, LMSpec, TrainConfig
 from repro.configs import ARCH_IDS, get_config, smoke_variant
-from repro.core import load_allocation
-from repro.core.delay_model import mec_network, packet_bits, scale_tau
 from repro.data.pipeline import PackedLMDataset, PipelineConfig
 from repro.models.model_zoo import build
 from repro.optim import optimizers
@@ -58,7 +59,12 @@ def train(cfg, steps: int = 20, batch: int = 4, seq: int = 64,
           lr: float = 3e-3, optimizer: str = "adam", *,
           federated: bool = False, fl_cfg: FLConfig | None = None,
           log_every: int = 5, seed: int = 0):
-    """Returns (params, losses, wall_clock_sim)."""
+    """Returns (params, losses, wall_clock_sim).  Federated runs hold one
+    sequence of `seq` targets per client (`batch` is not used) and train
+    with AdamW at a constant `lr`."""
+    if federated:
+        return train_federated(cfg, steps, seq, lr, fl_cfg or FLConfig(
+            n_clients=8), log_every=log_every, seed=seed)
     model = build(cfg)
     params = model.init_params(jax.random.PRNGKey(seed))
     opt_init, opt_update = optimizers.get(optimizer)
@@ -68,53 +74,39 @@ def train(cfg, steps: int = 20, batch: int = 4, seq: int = 64,
     grad_fn = jax.jit(jax.value_and_grad(
         lambda p, b: model.loss_fn(p, b, remat=False)))
 
-    # federated setup: n simulated clients, one delay node each
-    sim_wall = 0.0
-    if federated:
-        fl = fl_cfg or FLConfig(n_clients=8)
-        n_param = sum(np.prod(l.shape) for l in jax.tree_util.tree_leaves(params))
-        nodes = [scale_tau(nd, packet_bits(fl, int(n_param)))
-                 for nd in mec_network(fl, d_scalars_per_point=seq * 4)]
-        alloc = load_allocation.two_step_allocate(
-            nodes, [float(batch)] * fl.n_clients, server=None,
-            u_max=0.25 * batch * fl.n_clients,
-            m=float(batch * fl.n_clients))
-        t_star = alloc.t_star
-        p_ret = np.array([nd.cdf(t_star, float(l))
-                          for nd, l in zip(nodes, alloc.loads)])
-        rng = np.random.default_rng(seed + 5)
-
     losses = []
     for step in range(steps):
-        if not federated:
-            b = make_batch(cfg, batch, seq, seed + step)
-            loss, grads = grad_fn(params, b)
-        else:
-            # every client computes a gradient on its shard; stragglers drop
-            total, got, loss_acc = None, 0, 0.0
-            for j in range(fl.n_clients):
-                t_j = nodes[j].sample(rng, float(alloc.loads[j]))[0]
-                if t_j > t_star:
-                    continue
-                b = make_batch(cfg, batch, seq, seed + step * 131 + j)
-                loss_j, g_j = grad_fn(params, b)
-                w = 1.0 / max(p_ret[j], 1e-3)      # expected-return reweight
-                g_j = jax.tree_util.tree_map(lambda g: g * w, g_j)
-                total = g_j if total is None else jax.tree_util.tree_map(
-                    jnp.add, total, g_j)
-                loss_acc += float(loss_j)
-                got += 1
-            sim_wall += t_star
-            if total is None:
-                losses.append(float("nan"))
-                continue
-            grads = jax.tree_util.tree_map(lambda g: g / fl.n_clients, total)
-            loss = loss_acc / max(got, 1)
+        b = make_batch(cfg, batch, seq, seed + step)
+        loss, grads = grad_fn(params, b)
         params, opt_state = opt_update(params, grads, opt_state, lr_fn(step))
         losses.append(float(loss))
         if log_every and step % log_every == 0:
             print(f"step {step:4d} loss {float(loss):.4f}")
-    return params, losses, sim_wall
+    return params, losses, 0.0
+
+
+def train_federated(cfg, steps: int, seq: int, lr: float, fl: FLConfig, *,
+                    log_every: int = 5, seed: int = 0):
+    """`steps` coded-deadline rounds of `cfg` over ``fl.n_clients``
+    clients, each holding one packed sequence of `seq` next-token targets,
+    through ``build_experiment``.  Returns (params, per-round losses,
+    simulated MEC wall-clock)."""
+    from repro.api import build_experiment
+    tokens = np.concatenate([
+        make_batch(cfg, 1, seq + 1, seed, shard_id=j)["tokens"]
+        for j in range(fl.n_clients)])
+    spec = ExperimentSpec(
+        fl=fl, scheme="coded",
+        train=TrainConfig(optimizer="adamw", learning_rate=lr,
+                          lr_decay_epochs=()),
+        model=LMSpec(model=cfg, init_seed=seed))
+    res = build_experiment(spec, tokens).run(steps)
+    losses = [h.loss for h in res.history]
+    if log_every:
+        for h in res.history[::log_every]:
+            print(f"step {h.iteration:4d} loss {h.loss:.4f} "
+                  f"returned {h.returned}/{fl.n_clients}")
+    return res.theta["params"], losses, res.history[-1].wall_clock
 
 
 def main():

@@ -49,7 +49,8 @@ def _project_kv(p, x, positions, cfg, rope: bool = True):
     return k, v
 
 
-def _attend_single(q, k, v, q_pos, k_pos, window: int, causal: bool = True):
+def _attend_single(q, k, v, q_pos, k_pos, window: int, causal: bool = True,
+                   scale: float | None = None):
     """One-shot attention for q_len == 1 (decode).
 
     §Perf: the kv-chunk lax.scan forces XLA to materialize (all-gather) a
@@ -63,7 +64,8 @@ def _attend_single(q, k, v, q_pos, k_pos, window: int, causal: bool = True):
     K = k.shape[2]
     hd_v = v.shape[-1]
     G = H // K
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
     qr = q.reshape(B, S, K, G, hd).astype(jnp.float32) * scale
     s = jnp.einsum("bskgd,btkd->bskgt", qr, k.astype(jnp.float32))
     valid = jnp.broadcast_to(k_pos[None, :] >= 0, (S, k_pos.shape[0]))
@@ -81,20 +83,25 @@ def _attend_single(q, k, v, q_pos, k_pos, window: int, causal: bool = True):
 
 
 def _flash(q, k, v, q_pos, k_pos, window: int, chunk: int = 512,
-           causal: bool = True):
+           causal: bool = True, scale: float | None = None,
+           remat_chunks: bool = False):
     """Online-softmax attention.
 
     q: (B, S, H, hd); k, v: (B, T, K, hd); *_pos: (S,), (T,) global positions
-    (k_pos may contain -1 for invalid rolling-cache slots).
+    (k_pos may contain -1 for invalid rolling-cache slots).  `scale` is
+    the softmax scale (default hd^-0.5).  With `remat_chunks` the
+    backward pass keeps each chunk's carry and recomputes that chunk's
+    probabilities, never all chunks' (B, S, H, chunk) at once.
     Returns (B, S, H, hd).
     """
     B, S, H, hd = q.shape
     if S == 1:                          # decode: one-shot path (see above)
-        return _attend_single(q, k, v, q_pos, k_pos, window, causal)
+        return _attend_single(q, k, v, q_pos, k_pos, window, causal, scale)
     T, K = k.shape[1], k.shape[2]
     hd_v = v.shape[-1]                  # may differ from hd (MLA)
     G = H // K
-    scale = 1.0 / np.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
     qr = q.reshape(B, S, K, G, hd).astype(jnp.float32) * scale
     chunk = min(chunk, T)
     n_chunks = T // chunk if T % chunk == 0 else -(-T // chunk)
@@ -129,7 +136,9 @@ def _flash(q, k, v, q_pos, k_pos, window: int, chunk: int = 512,
     m0 = jnp.full((B, S, K, G), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, S, K, G), jnp.float32)
     a0 = jnp.zeros((B, S, K, G, hd_v), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), (kc, vc, pc))
+    (m, l, acc), _ = jax.lax.scan(
+        jax.checkpoint(step) if remat_chunks else step, (m0, l0, a0),
+        (kc, vc, pc))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.reshape(B, S, H, hd_v).astype(q.dtype)
 

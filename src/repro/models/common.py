@@ -1,6 +1,8 @@
 """Shared model building blocks (functional, explicit param pytrees)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -43,14 +45,50 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, hd) or (..., S, hd); positions: (..., S) int."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor 0.1 mscale ln(scale) + 1."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` makes them: interpolated (divided by
+    ``yarn.factor``) below the correction dim of ``beta_slow`` rotations,
+    original above that of ``beta_fast``, and a linear ramp between, over
+    ``original_max_position_embeddings`` positions."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    extra = rope_freqs(head_dim, theta)
+    keep = 1.0 - ramp                       # weight of the original freqs
+    return (extra / yarn.factor * (1.0 - keep) + extra * keep) \
+        .astype(np.float32)
+
+
+def apply_rope(x, positions, theta: float, yarn=None):
+    """x: (..., S, H, hd) or (..., S, hd); positions: (..., S) int.  With
+    `yarn` (a ``YarnConfig``) the frequencies are YaRN's and cos/sin are
+    scaled by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     hd = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(hd, theta))              # (hd/2,)
+    freqs = jnp.asarray(rope_freqs(hd, theta) if yarn is None
+                        else yarn_freqs(hd, theta, yarn))   # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     if x.ndim == ang.ndim + 1:                              # head axis present
         ang = ang[..., None, :]                             # (..., S, 1, hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        amp = (yarn_mscale(yarn.factor, yarn.mscale)
+               / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if amp != 1.0:
+            cos, sin = cos * amp, sin * amp
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -1,7 +1,12 @@
 """DeepSeek-V2 Multi-head Latent Attention (MLA).
 
 The KV cache stores only the compressed latent c_kv (rank `kv_lora_rank`)
-plus the shared RoPE key — ~10x smaller than a GQA cache.  The baseline
+plus the shared RoPE key — ~10x smaller than a GQA cache.  With
+``cfg.yarn`` the rope dims take YaRN's frequencies and the softmax scale
+grows by mscale(factor, mscale_all_dim)^2, as DeepSeek-V2 does.  RoPE
+rotates split halves of the rope dims, where DeepSeek-V2 first
+de-interleaves pairs: a fixed permutation of the rope columns of
+``wq`` and ``w_dkv``.  The baseline
 decode path decompresses K/V from the latent each step (matches the paper's
 formulation); absorbing W_uk into the query is a §Perf optimization measured
 in EXPERIMENTS.md.
@@ -11,8 +16,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import apply_rope, dense_init, rms_norm
+from repro.models.common import apply_rope, dense_init, rms_norm, yarn_mscale
 from repro.models.attention import _flash
+
+
+def softmax_scale(cfg) -> float:
+    """(qk_nope + qk_rope)^-0.5, times mscale(factor, mscale_all_dim)^2
+    under YaRN."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.yarn
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def init_mla(key, cfg, dtype):
@@ -34,7 +50,7 @@ def _q_proj(p, x, positions, cfg):
     m = cfg.mla
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_pe = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta, cfg.yarn)
     return jnp.concatenate([q_nope, q_pe], axis=-1)
 
 
@@ -43,7 +59,7 @@ def _latent(p, x, positions, cfg):
     ckv = x @ p["w_dkv"]                                    # (B,S,lora+rope)
     c, k_pe = jnp.split(ckv, [m.kv_lora_rank], axis=-1)
     c = rms_norm(c, p["kv_norm"], cfg.norm_eps)
-    k_pe = apply_rope(k_pe, positions, cfg.rope_theta)      # (B,S,rope)
+    k_pe = apply_rope(k_pe, positions, cfg.rope_theta, cfg.yarn)  # (B,S,rope)
     return c, k_pe
 
 
@@ -59,12 +75,15 @@ def _decompress(p, c, k_pe, cfg):
 
 
 def mla_train(p, x, positions, cfg, window: int = 0):
-    q = _q_proj(p, x, positions[None, :], cfg)
-    c, k_pe = _latent(p, x, positions[None, :], cfg)
-    k, v = _decompress(p, c, k_pe, cfg)
-    win = window if window else cfg.swa_window
-    out = _flash(q, k, v, positions, positions, win)        # kv heads == H
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    with jax.named_scope("mla"):
+        q = _q_proj(p, x, positions[None, :], cfg)
+        c, k_pe = _latent(p, x, positions[None, :], cfg)
+        k, v = _decompress(p, c, k_pe, cfg)
+        win = window if window else cfg.swa_window
+        # the backward pass recomputes one kv chunk at a time
+        out = _flash(q, k, v, positions, positions, win,
+                     scale=softmax_scale(cfg), remat_chunks=True)
+        return jnp.einsum("bshk,hkd->bsd", out, p["wo"])    # kv heads == H
 
 
 def init_mla_cache(cfg, batch: int, max_seq: int, dtype, window: int = 0):
@@ -80,7 +99,7 @@ def mla_prefill(p, x, positions, cfg, cache, window: int = 0):
     c, k_pe = _latent(p, x, positions[None, :], cfg)
     k, v = _decompress(p, c, k_pe, cfg)
     win = window if window else cfg.swa_window
-    out = _flash(q, k, v, positions, positions, win)
+    out = _flash(q, k, v, positions, positions, win, scale=softmax_scale(cfg))
     S = x.shape[1]
     slots = cache["c"].shape[1]
     if slots >= S:
@@ -107,6 +126,7 @@ def mla_decode(p, x, pos, cfg, cache, window: int = 0):
     cp = jax.lax.dynamic_update_slice(
         cache["pos"], jnp.full((1,), pos, jnp.int32), (slot,))
     k, v = _decompress(p, cc, ck, cfg)                      # baseline path
-    out = _flash(q, k, v, jnp.full((1,), pos, jnp.int32), cp, win)
+    out = _flash(q, k, v, jnp.full((1,), pos, jnp.int32), cp, win,
+                 scale=softmax_scale(cfg))
     return (jnp.einsum("bshk,hkd->bsd", out, p["wo"]),
             {"c": cc, "kpe": ck, "pos": cp})

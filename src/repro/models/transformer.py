@@ -138,11 +138,23 @@ def init_cache(cfg, batch: int, max_seq: int, window: int = 0):
 
 # ------------------------------------------------------------------- layers
 
+def _zero_aux(cfg, batch: int) -> dict:
+    """Per-layer statistics summed over the layers: the balance loss
+    (per sequence (B,) for DeepSeek's, else a scalar), and the held
+    experts' routed assignments and expert-matmul rows (`moe_ffn_held`)."""
+    seq = cfg.moe is not None and cfg.moe.dropless
+    return {"balance": jnp.zeros((batch,) if seq else (), jnp.float32),
+            "routed": jnp.zeros((), jnp.int32),
+            "rows": jnp.zeros((), jnp.int32)}
+
+
 def _layer_apply(spec, p, x, cfg, mode, positions=None, pos=None,
-                 cache=None, window=0, chunked=True):
-    """One (mixer, ffn) layer.  Returns (x, new_cache, aux)."""
+                 cache=None, window=0, chunked=True, token_mask=None):
+    """One (mixer, ffn) layer.  Returns (x, new_cache, aux) with aux as
+    `_zero_aux` lays it out; `token_mask` (B, S) marks the tokens that
+    route to experts (dropless MoE only; None: all)."""
     mixer, ffn = spec
-    aux = jnp.zeros((), jnp.float32)
+    aux = _zero_aux(cfg, x.shape[0])
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     new_cache = {}
     if mixer == "attn":
@@ -184,9 +196,13 @@ def _layer_apply(spec, p, x, cfg, mode, positions=None, pos=None,
 
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
     if ffn == "dense":
-        x = x + swiglu(h, p["ffn"]["w1"], p["ffn"]["w3"], p["ffn"]["w2"])
+        with jax.named_scope("dense_ffn"):
+            x = x + swiglu(h, p["ffn"]["w1"], p["ffn"]["w3"], p["ffn"]["w2"])
     elif ffn == "moe":
-        out, aux = moe.moe_ffn(p["moe"], h, cfg)
+        if cfg.moe.dropless:
+            out, aux = moe.moe_ffn_held(p["moe"], h, cfg, token_mask)
+        else:
+            out, aux["balance"] = moe.moe_ffn(p["moe"], h, cfg)
         x = x + out
     elif ffn == "rwkv_ffn":
         st = new_cache.get("rwkv") or (cache["rwkv"] if cache else
@@ -199,9 +215,10 @@ def _layer_apply(spec, p, x, cfg, mode, positions=None, pos=None,
 
 
 def _run_stages(cfg, params, x, mode, positions=None, pos=None, cache=None,
-                window=0, remat=False, chunked=True):
-    """Scan over every stage.  Returns (x, new_cache, total_aux)."""
-    total_aux = jnp.zeros((), jnp.float32)
+                window=0, remat=False, chunked=True, token_mask=None):
+    """Scan over every stage.  Returns (x, new_cache, aux), aux the
+    layers' `_zero_aux` statistics summed."""
+    total_aux = _zero_aux(cfg, x.shape[0])
     new_cache = {}
     for si, (pattern, count) in enumerate(stages(cfg)):
         sp = params[f"stage{si}"]
@@ -216,9 +233,9 @@ def _run_stages(cfg, params, x, mode, positions=None, pos=None, cache=None,
                     spec, layer_p[f"l{i}"], x, cfg, mode,
                     positions=positions, pos=pos,
                     cache=None if layer_c is None else layer_c[f"l{i}"],
-                    window=window, chunked=chunked)
+                    window=window, chunked=chunked, token_mask=token_mask)
                 lc_out[f"l{i}"] = c
-                aux = aux + a
+                aux = jax.tree_util.tree_map(jnp.add, aux, a)
             return (x, aux), lc_out
 
         body_fn = jax.checkpoint(body) if remat else body
@@ -281,7 +298,39 @@ def loss_fn(cfg, params, batch, window: int = 0, remat: bool = True,
     labels = batch["labels"]
     mask = (labels >= 0).astype(jnp.float32)
     loss = softmax_cross_entropy(logits, jnp.maximum(labels, 0), mask)
-    return loss + aux
+    return loss + jnp.mean(aux["balance"])
+
+
+def client_losses(cfg, params, tokens, loads, remat: bool = True):
+    """Per-client objectives of the federated language-model round.
+
+    tokens: (B, S + 1) ids, one sequence per client; loads: (B,) ints,
+    client b's load being its first ``loads[b]`` next-token targets.  A
+    target past the load is masked out of the loss and routes to no
+    expert; under causal attention that is the sequence truncated to its
+    load.  Returns ``(L, stats)``: ``L[b] = sum_{t < l_b} nll_{b,t} +
+    l_b * balance_b`` (the per-token loss plus the balance loss, summed
+    over the load), ``stats`` the per-client ``nll`` sums and the summed
+    ``routed`` / ``rows`` counts of the held experts.
+    """
+    inp, labels = tokens[:, :-1], tokens[:, 1:]
+    S = inp.shape[1]
+    mask = (jnp.arange(S)[None, :] < loads[:, None]).astype(jnp.float32)
+    x = _embed_tokens(cfg, params, inp)
+    positions = jnp.arange(S, dtype=jnp.int32)
+    x, _, aux = _run_stages(cfg, params, x, "train", positions=positions,
+                            remat=remat, token_mask=mask)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        logits = _logits(cfg, params, x).astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+        picked = jnp.sum(jnp.where(ids == labels[..., None], logits, 0.0),
+                         axis=-1)
+        nll = jnp.sum((lse - picked) * mask, axis=-1)
+    balance = jnp.broadcast_to(aux["balance"], nll.shape)
+    L = nll + loads.astype(jnp.float32) * balance
+    return L, {"nll": nll, "routed": aux["routed"], "rows": aux["rows"]}
 
 
 def hidden_states(cfg, params, batch, window: int = 0, chunked: bool = True):

@@ -21,6 +21,9 @@ the hierarchical loop, whose rounds are host work anyway):
   ``solver/two_step``         two-step load-allocation solve
   ``encode/parity``           parity encode and aggregate, ending in a
                               sync on the parity set
+  ``model/init``              a model spec's weights and AdamW state made
+                              on the device (`repro.core.lm_round`),
+                              ending in a sync on them
   ``trace/generate``          channel-trace block generation
   ``block/run``               one flat `run_block` (``cursor``)
   ``block/prepare``           its delay draws, scan inputs, fault rows
@@ -64,6 +67,12 @@ Counters (``{name: {"events", "total"}}``):
   ``hier/h2d_bytes``          bytes of the host arrays a hierarchical round
                               hands to the device (shard blocks and return
                               masks, as f32)
+  ``round/tokens``            tokens a model round computes: every
+                              client's whole sequence (n S a round)
+  ``moe/routed``              token-expert assignments to the held experts
+                              (dropless MoE), from the packed fetch
+  ``moe/expert_rows``         rows the held experts' grouped matmuls
+                              compute, each group in whole kernel tiles
   ==========================  ==============================================
 
 Timing never touches an RNG stream or any value that flows into a

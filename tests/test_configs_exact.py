@@ -22,7 +22,7 @@ EXPECT = {
                                  d_model=8192, n_heads=64, n_kv_heads=8,
                                  d_ff=24576, vocab=65536),
     "deepseek-v2-lite-16b": dict(arch_type="moe", n_layers=27, d_model=2048,
-                                 n_heads=16, n_kv_heads=16, d_ff=1408,
+                                 n_heads=16, n_kv_heads=16, d_ff=10944,
                                  vocab=102400),
     "whisper-base": dict(arch_type="audio", n_layers=6, d_model=512,
                          n_heads=8, n_kv_heads=8, d_ff=2048, vocab=51865),
@@ -47,6 +47,22 @@ def test_family_features():
     assert ds.mla.kv_lora_rank == 512
     assert ds.moe.num_experts == 64 and ds.moe.top_k == 6
     assert ds.moe.num_shared_experts == 2
+    # the published config.json's keys: dense first layer 10944 wide,
+    # experts 1408 wide, unrenormalised top-6 gates scaled by 1, dropless
+    # routing with the per-sequence balance loss, YaRN RoPE, eps 1e-6
+    assert ds.d_ff == 10944 and ds.moe.d_ff_expert == 1408
+    assert ds.moe.norm_topk_prob is False
+    assert ds.moe.routed_scaling_factor == 1.0
+    assert ds.moe.dropless       # with the per-sequence balance loss
+    assert ds.moe.held == (0, 64)
+    assert ds.norm_eps == 1e-6 and ds.rope_theta == 10000.0
+    assert not ds.tie_embeddings
+    y = ds.yarn
+    assert (y.factor, y.beta_fast, y.beta_slow) == (40.0, 32.0, 1.0)
+    assert y.original_max_position_embeddings == 4096
+    assert y.mscale == y.mscale_all_dim == 0.707
+    assert (ds.mla.qk_nope_head_dim, ds.mla.qk_rope_head_dim,
+            ds.mla.v_head_dim) == (128, 64, 128)
     jamba = get_config("jamba-1.5-large-398b")
     assert jamba.ssm.attn_every_n == 8          # 1:7 attn:mamba
     assert jamba.moe.num_experts == 16 and jamba.moe.top_k == 2

@@ -127,3 +127,32 @@ def test_vmem_guard_matches_compiler(one_chip, monkeypatch, make, fits,
     monkeypatch.setattr(fused_mod, "_check_fused_vmem", lambda *a: None)
     with pytest.raises(Exception, match="(?i)vmem"):
         _compile(fn, one_chip, *shapes)
+
+
+def test_held_expert_layer_compiles_for_v5e(one_chip):
+    """DeepSeek-V2-Lite's held-expert MoE layer at its published widths
+    (D 2,048, experts 1,408 wide, 64-wide router, top-6, 8 experts held,
+    2 shared), forward and backward over 2 x 512 tokens: the grouped
+    matmuls lower to the TPU's ragged-dot kernel."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import moe
+
+    full = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(full, dtype="float32", moe=dataclasses.replace(
+        full.moe, experts_held=(8, 8)))
+    p = jax.eval_shape(lambda k: moe.init_moe(k, cfg, jnp.float32),
+                       jax.random.PRNGKey(0))
+    p = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), p)
+    x = jax.ShapeDtypeStruct((2, 512, cfg.d_model), jnp.float32,
+                             sharding=one_chip)
+
+    def loss(p_, x_):
+        out, st = moe.moe_ffn_held(p_, x_, cfg)
+        return jnp.sum(out * out) + jnp.sum(st["balance"])
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile() \
+        .as_text()
+    assert "ragged-dot" in text
