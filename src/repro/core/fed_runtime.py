@@ -822,6 +822,7 @@ class Experiment:
         self._attr_blocks: "list[dict]" = []
         if self.lm is not None:
             lm_round.validate(self)
+            self._attn_scores = lm_round.attn_scores(self.lm.model, self.l)
         with obs_spans.span("setup/experiment"):
             self.scheme_obj.setup(self)
         self.privacy_eps = (None if self.lm is not None
@@ -1346,6 +1347,9 @@ class Experiment:
         if self.lm is not None:
             ext = lm_round.unpack_extras(out[4 * K + 1:], K)
             obs_spans.count("round/tokens", K * self.n * self.l)
+            scores, causal = self._attn_scores
+            obs_spans.count("attn/scores", K * self.n * scores)
+            obs_spans.count("attn/causal_scores", K * self.n * causal)
             obs_spans.count("moe/routed", int(ext["routed"].sum()))
             obs_spans.count("moe/expert_rows", int(ext["rows"].sum()))
             lm_log = {k: np.concatenate([state.lm_log[k], ext[k]])

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models import transformer
+from repro.models import attention, transformer
 from repro.obs import spans as obs_spans
 
 #: per-round outputs of the round after t_round, n_ret, n_masked, skipped
@@ -77,6 +77,17 @@ def validate(exp) -> None:
             "ssm", "hybrid"):
         raise ValueError(f"model {cfg.name!r}: the federated round trains "
                          "decoder-only attention models")
+
+
+def attn_scores(cfg, S: int) -> tuple[int, int]:
+    """Per sequence of S positions, summed over the model's attention
+    layers, heads not counted: the query-key scores the attention
+    forward computes (`attention.causal_block_scores`), and those of the
+    causal triangle, S (S + 1) / 2 a layer."""
+    layers = sum(count * sum(mixer in ("attn", "mla") for mixer, _ in pat)
+                 for pat, count in transformer.stages(cfg))
+    return (layers * attention.causal_block_scores(S, cfg.swa_window),
+            layers * S * (S + 1) // 2)
 
 
 def _check_weights(cfg, params) -> None:

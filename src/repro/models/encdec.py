@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from repro.models.common import (dense_init, dtype_of, embed_init,
                                  rms_norm, sinusoidal_positions,
                                  softmax_cross_entropy)
-from repro.models.attention import _flash
+from repro.models.attention import _flash, _flash_causal
 
 
 def _init_qkvo(key, cfg, dtype):
@@ -106,7 +106,7 @@ def _dec_layer(cfg, p, x, enc_out, pos_q, mode, cache=None, window=0):
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     q, k, v = _qkv(p["self"], h)
     if mode in ("train", "prefill"):
-        o = _flash(q, k, v, pos_q, pos_q, window)
+        o = _flash_causal(q, k, v, pos_q, window)
         if mode == "prefill":
             S = x.shape[1]
             slots = cache["k"].shape[1]

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import apply_rope, dense_init, rms_norm, yarn_mscale
-from repro.models.attention import _flash
+from repro.models.attention import _flash, _flash_causal
 
 
 def softmax_scale(cfg) -> float:
@@ -81,8 +81,8 @@ def mla_train(p, x, positions, cfg, window: int = 0):
         k, v = _decompress(p, c, k_pe, cfg)
         win = window if window else cfg.swa_window
         # the backward pass recomputes one kv chunk at a time
-        out = _flash(q, k, v, positions, positions, win,
-                     scale=softmax_scale(cfg), remat_chunks=True)
+        out = _flash_causal(q, k, v, positions, win,
+                            scale=softmax_scale(cfg), remat_chunks=True)
         return jnp.einsum("bshk,hkd->bsd", out, p["wo"])    # kv heads == H
 
 
@@ -99,7 +99,7 @@ def mla_prefill(p, x, positions, cfg, cache, window: int = 0):
     c, k_pe = _latent(p, x, positions[None, :], cfg)
     k, v = _decompress(p, c, k_pe, cfg)
     win = window if window else cfg.swa_window
-    out = _flash(q, k, v, positions, positions, win, scale=softmax_scale(cfg))
+    out = _flash_causal(q, k, v, positions, win, scale=softmax_scale(cfg))
     S = x.shape[1]
     slots = cache["c"].shape[1]
     if slots >= S:

@@ -73,6 +73,12 @@ Counters (``{name: {"events", "total"}}``):
                               (dropless MoE), from the packed fetch
   ``moe/expert_rows``         rows the held experts' grouped matmuls
                               compute, each group in whole kernel tiles
+  ``attn/scores``             query-key scores a model round's attention
+                              forward computes, per sequence and layer
+                              (heads not counted): the causal blocks
+                              it visits, padding included
+  ``attn/causal_scores``      the causal triangle over the same
+                              sequences and layers, S (S + 1) / 2 each
   ==========================  ==============================================
 
 Timing never touches an RNG stream or any value that flows into a

@@ -22,6 +22,7 @@ from repro.configs import get_config
 from repro.core import lm_round
 from repro.models import mla, moe, transformer
 from repro.models.common import swiglu, yarn_freqs
+from repro.obs import spans as obs_spans
 
 N_CLIENTS, S = 4, 32
 FIRST, HELD = 2, 2
@@ -189,6 +190,25 @@ def test_round_reports_routed_rows_and_returns(played):
     assert np.all(log["rows"] >= log["routed"])
     ret = returned(log["ret_bits"], exp.n)
     np.testing.assert_array_equal(ret.sum(axis=1), state.n_ret)
+
+
+def test_round_counts_attention_scores_of_its_shapes():
+    """A model block counts the query-key scores its attention forward
+    computes and the causal triangle's, per client sequence and layer
+    (heads not counted): S = 32 is one block of 32^2 against 32 * 33 / 2,
+    a ratio of 2 * 32 / 33."""
+    exp, _ = experiment()
+    state = exp.init_state(10)
+    with obs_spans.collecting() as spans:
+        exp.run_block(state)
+        got = spans.counters()
+    layers = exp.lm.model.n_layers
+    assert got["attn/scores"] == {"events": 1,
+                                  "total": N_CLIENTS * layers * S * S}
+    assert got["attn/causal_scores"] == {
+        "events": 1, "total": N_CLIENTS * layers * S * (S + 1) // 2}
+    assert (got["attn/scores"]["total"] / got["attn/causal_scores"]["total"]
+            == pytest.approx(2 * S / (S + 1)))
 
 
 def test_yarn_frequencies_and_scale_match_closed_form():
