@@ -7,7 +7,8 @@ scheme registry (repro.core.schemes) makes the straggler-mitigation
 strategy pluggable, and `build_experiment` turns spec + data into a
 runnable `Experiment` whose ``.run`` / ``.run_multi`` / ``.sweep`` all
 flow through the shared compiled-step machinery
-(`fed_runtime.build_consts` / `fed_runtime.build_step`).
+(`Experiment.build_consts`, `fed_runtime.build_step` and the scheme's
+`round_rule`).
 
     from repro.api import ExperimentSpec, build_experiment
     from repro.config import FLConfig, TrainConfig

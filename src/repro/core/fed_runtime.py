@@ -6,12 +6,13 @@ rounds in a simulated wireless MEC network.  Compute/communication delays are
 wall-clock is the quantity all of Fig. 4/5 and Tables II/III are measured in.
 
 The straggler-mitigation scheme is a pluggable registry object
-(``repro.core.schemes``: naive / greedy / ideal / coded / partial_coded,
-plus anything registered since) that owns the deployment setup and its
-contributions to the compiled step; `Experiment` is built from a frozen
-`ExperimentSpec` (``repro.api.build_experiment``).  The kwargs-era
-`FederatedSimulation` front-end has been removed (a stub raising a
-pointed error remains).
+(``repro.core.schemes``) that owns the deployment setup, its
+contributions to the compiled step, and the per-round rule — deadline,
+returned mask, denominator (`Scheme.round_rule`) — that `build_step`
+calls; the runtime holds no per-scheme branch.  `Experiment` is built
+from a frozen `ExperimentSpec` (``repro.api.build_experiment``).  The
+kwargs-era `FederatedSimulation` front-end has been removed (a stub
+raising a pointed error remains).
 
 Block-structured resumable runs
 -------------------------------
@@ -60,12 +61,12 @@ trace (Gilbert–Elliott erasure bursts, shadowing/MCS rate hopping, compute
 drift, churn) instead of the stationary model — still one compiled scan,
 with a per-round availability row joining the scan inputs.  The static
 profile reproduces the stationary engine bit-exactly.  Adaptive schemes
-(``adaptive_coded``/``adaptive_greedy``) additionally run the
+(``Scheme.adaptive``) additionally run the
 ``repro.net.estimator.AdaptiveController`` control loop on the host ahead
-of the scan: online (mu, tau, p) estimation from round telemetry,
-re-solving the load allocation every ``adapt_every`` rounds, applied as
-block-indexed mask re-weighting so shapes (and the compiled step) never
-change.
+of the scan: online (mu, tau, p) estimation from round telemetry and a
+re-plan every ``adapt_every`` rounds, whose per-round controls join the
+scan inputs (`Scheme.segment_inputs`) so shapes (and the compiled step)
+never change.
 
 Robustness (``ExperimentSpec.fault_profile``, ``repro.faults``): the
 compiled step carries two guards.  The non-finite guard
@@ -313,13 +314,13 @@ def _make_grad_sum(static: dict):
 def build_step(static: dict):
     """One scan step ``step(consts, carry, inp)``.
 
-    `static` (Python-level, fixed at trace time): scheme, n, n_wait, l2, m,
-    l, fused, mesh, use_pallas, interpret, collect_theta, channel, guard,
-    faults, stale.
+    `static` (Python-level, fixed at trace time): scheme (the registered
+    `repro.core.schemes.Scheme` object), n, n_wait, l2, m, l, fused,
+    mesh, use_pallas, interpret, collect_theta, channel, guard, faults,
+    stale.
     `consts` (arrays, vmappable): gx (rows, L, q), gy (rows, L, c), gmask
-    (rows, L), ret_tail (rows - n,); coded adds t_star (), active (n,) and —
-    when unfused — par_x (u, q) / par_y (u, c); adaptive_coded adds
-    gmask_blocks (B, rows, L).
+    (rows, L), ret_tail (rows - n,), plus what the scheme adds
+    (`Scheme.extra_consts`, `Scheme.segment_inputs`).
 
     ``carry`` is ``(theta, lr_scale)`` — lr_scale is the divergence
     guard's backoff multiplier, 1.0 until a non-finite iterate is
@@ -327,35 +328,27 @@ def build_step(static: dict):
     with ``stale=True`` (stale-update fault injection) it grows the
     previous round's iterate: ``(theta, lr_scale, theta_prev)``.
 
-    ``inp`` is ``(t_row, lr)`` on the stationary path.  With
-    ``channel=True`` (a network trace drives the run) it grows a per-round
-    availability row: ``(t_row, lr, active)`` — churned-out clients never
-    count as returned, and the naive/greedy deadlines range over the
-    clients actually present.  The adaptive step kinds extend it further
-    with their per-round control values: ``(..., t_star_r, block)`` for
-    adaptive_coded (the block index selects that block's re-allocated
-    fused load mask — pure mask re-weighting, shapes never change) and
-    ``(..., n_wait_r)`` for adaptive_greedy.  With ``faults=True``
-    (`repro.faults`) two fault inputs ride at the very END of the tuple:
-    ``(..., fcode, fpar)`` — per-client fault codes (n,) int32 and the
-    round's corrupted-parity flag () f32.  Under the static channel
-    profile `active` is identically 1.0 and every extra operation is an
-    IEEE no-op, so trajectories stay bit-identical to the stationary
-    path; likewise guard-on fault-free steps compile to bit-identical
-    trajectories (see `_guard_and_sum`).
+    ``inp`` is ``(t_row, lr, *ctl)``: ``ctl`` is what the scheme's
+    `Scheme.round_rule` reads — empty on the stationary path, the
+    per-round availability row ``(active,)`` when a network trace drives
+    the run (``channel=True``), and the adaptive schemes' per-round
+    control values after it.  The rule gives the round's deadline, the
+    returned mask and the denominator; the step keeps what every scheme
+    shares.  With ``faults=True`` (`repro.faults`) two fault inputs ride
+    at the very END of the tuple: ``(..., fcode, fpar)`` — per-client
+    fault codes (n,) int32 and the round's corrupted-parity flag () f32.
+    Under the static channel profile `active` is identically 1.0 and
+    every extra operation is an IEEE no-op, so trajectories stay
+    bit-identical to the stationary path; likewise guard-on fault-free
+    steps compile to bit-identical trajectories (see `_guard_and_sum`).
 
-    Scheme dispatch is static, so each scheme compiles to a straight-line
-    fused update.
+    The scheme is static, so each compiles to a straight-line fused
+    update.
     """
     scheme = static["scheme"]
-    n = static["n"]
-    n_wait = static["n_wait"]
     l2 = static["l2"]
-    m = static["m"]
-    l = static["l"]
     fused = static["fused"]
     fused_embed = static.get("fused_embed", False)
-    channel = static.get("channel", False)
     guard = static.get("guard", True)
     faults = static.get("faults", False)
     stale = static.get("stale", False)
@@ -363,6 +356,10 @@ def build_step(static: dict):
     use_pallas = static["use_pallas"]
     interpret = static["interpret"]
     grad_sum = _make_grad_sum(static)
+    # a coded scheme's parity set is the first tail row of the fused
+    # tensors, or rides apart in consts (par_x / par_y) when unfused
+    parity_row = fused and scheme.coded
+    parity_apart = scheme.coded and not fused
 
     def step(consts, carry, inp):
         if stale:
@@ -371,72 +368,9 @@ def build_step(static: dict):
             theta, lr_scale = carry
         if faults:
             *inp, fcode, fpar = inp
-            inp = tuple(inp)
-        gmask = consts["gmask"]
-        if scheme == "adaptive_coded":
-            t_row, lr, active, t_star_r, block = inp
-        elif scheme == "adaptive_greedy":
-            t_row, lr, active, n_wait_r = inp
-        elif channel:
-            t_row, lr, active = inp
-        else:
-            t_row, lr = inp
-        if scheme == "naive":
-            if channel:
-                ret_real = active
-                n_ret = jnp.sum(active).astype(jnp.int32)
-                t_round = jnp.max(jnp.where(active > 0, t_row, 0.0))
-            else:
-                n_ret = jnp.int32(n)
-                t_round = jnp.max(t_row)
-                ret_real = jnp.ones_like(t_row)
-            denom = m
-        elif scheme == "greedy":
-            if channel:
-                # deadline = n_wait-th fastest among the clients present
-                srt = jnp.sort(jnp.where(active > 0, t_row, jnp.inf))
-                n_act = jnp.sum(active).astype(jnp.int32)
-                k_eff = jnp.clip(jnp.minimum(jnp.int32(n_wait), n_act), 1, n)
-                t_round = jnp.where(n_act > 0, jnp.take(srt, k_eff - 1), 0.0)
-                ret_real = (t_row <= t_round).astype(t_row.dtype) * active
-            else:
-                t_round = jnp.sort(t_row)[n_wait - 1]
-                ret_real = (t_row <= t_round).astype(t_row.dtype)
-            n_ret = jnp.sum(ret_real).astype(jnp.int32)
-            denom = jnp.maximum(n_ret, 1).astype(jnp.float32) * l
-        elif scheme == "coded":
-            t_star = consts["t_star"]
-            t_round = t_star
-            by_deadline = (t_row <= t_star).astype(t_row.dtype)
-            ret_real = by_deadline * consts["active"]
-            if channel:
-                by_deadline = by_deadline * active
-                ret_real = ret_real * active
-            n_ret = jnp.sum(by_deadline).astype(jnp.int32)
-            denom = m
-        elif scheme == "ideal":
-            # deterministic no-straggler floor: all clients, full load,
-            # fixed round clock (the sampled t_row is ignored)
-            t_round = consts["t_ideal"]
-            ret_real = active if channel else jnp.ones_like(t_row)
-            n_ret = jnp.sum(ret_real).astype(jnp.int32)
-            denom = m
-        elif scheme == "adaptive_coded":
-            t_round = t_star_r
-            ret_real = (t_row <= t_star_r).astype(t_row.dtype) * active
-            n_ret = jnp.sum(ret_real).astype(jnp.int32)
-            gmask = consts["gmask_blocks"][block]
-            denom = m
-        elif scheme == "adaptive_greedy":
-            srt = jnp.sort(jnp.where(active > 0, t_row, jnp.inf))
-            n_act = jnp.sum(active).astype(jnp.int32)
-            k_eff = jnp.clip(jnp.minimum(n_wait_r, n_act), 1, n)
-            t_round = jnp.where(n_act > 0, jnp.take(srt, k_eff - 1), 0.0)
-            ret_real = (t_row <= t_round).astype(t_row.dtype) * active
-            n_ret = jnp.sum(ret_real).astype(jnp.int32)
-            denom = jnp.maximum(n_ret, 1).astype(jnp.float32) * l
-        else:
-            raise ValueError(scheme)
+        t_row, lr, *ctl = inp
+        t_round, ret_real, n_ret, denom, gmask = scheme.round_rule(
+            static, consts, t_row, tuple(ctl))
         # ret_tail covers the pseudo-client rows: the always-active parity
         # row (fused coded) and any zero-mask mesh padding rows.
         ret = jnp.concatenate([ret_real.astype(jnp.float32),
@@ -453,7 +387,7 @@ def build_step(static: dict):
                           jnp.float32(0.0)))
             tail_n = consts["ret_tail"].shape[0]
             tail_bad = jnp.zeros((tail_n,), jnp.float32)
-            if fused and scheme in ("coded", "adaptive_coded") and tail_n:
+            if parity_row and tail_n:
                 tail_bad = tail_bad.at[0].set(
                     jnp.where(fpar > 0, jnp.float32(jnp.nan),
                               jnp.float32(0.0)))
@@ -480,7 +414,7 @@ def build_step(static: dict):
             n_masked = m_fresh + m_stale
         else:
             g_sum, n_masked = sum_at(theta, ret)
-        if scheme == "coded" and not fused:
+        if parity_apart:
             g_par = aggregation.coded_gradient(
                 consts["par_x"], consts["par_y"], theta, pnr_c=0.0,
                 use_pallas=use_pallas, interpret=interpret)
@@ -703,7 +637,7 @@ class Experiment:
         # --- network dynamics (repro.net): channel trace + adaptation
         self.channel = spec.resolved_channel()
         self.adapt_every = spec.adapt_every
-        self.adaptive = self.step_kind.startswith("adaptive")
+        self.adaptive = self.scheme_obj.adaptive
         if self.adaptive:
             if self.engine == "legacy":
                 raise ValueError(
@@ -917,7 +851,7 @@ class Experiment:
     def step_static(self, collect_theta: bool = False) -> dict:
         """Python-static step parameters matching `build_consts`."""
         return {
-            "scheme": self.step_kind,
+            "scheme": self.scheme_obj,
             "n": self.n,
             "n_wait": self.n_wait,
             "l2": self.train.l2_reg,
@@ -1290,9 +1224,6 @@ class Experiment:
                 times = sample_round_times(
                     self.nodes, np.asarray(self.loads, float), rng, K)
                 xs = (times, lrs)
-                if obs_spans.enabled():
-                    self._attr_blocks.append({"times": times,
-                                              "active": None})
             else:
                 with obs_spans.span("trace/generate"):
                     trace_block, trace_new = generate_trace_block(
@@ -1303,36 +1234,19 @@ class Experiment:
                     est.load_state_dict(state.est)
                     seg = plan_segment(self, est, trace_block, r0, r0 + K,
                                        state.controls, rng)
-                    xs = (seg.times, lrs, seg.active)
-                    if self.step_kind == "adaptive_coded":
-                        consts = dict(consts)
-                        consts["gmask_blocks"] = seg.gmask_blocks
-                        xs = xs + (seg.t_star_r, seg.block_idx)
-                    else:
-                        xs = xs + (seg.n_wait_r,)
+                    extra, more_consts = self.scheme_obj.segment_inputs(
+                        self, seg)
+                    consts = dict(consts, **more_consts)
+                    xs = (seg.times, lrs, seg.active) + extra
                     est_new = est.state_dict()
                     controls_new = seg.controls
                     sched_new = _append_sched(state.sched, seg)
-                    if obs_spans.enabled():
-                        self._attr_blocks.append({
-                            "times": np.asarray(seg.times),
-                            "active": np.asarray(seg.active),
-                            "t_star_r": (np.asarray(seg.t_star_r)
-                                         if self.step_kind == "adaptive_coded"
-                                         else None),
-                            "n_wait_r": (np.asarray(seg.n_wait_r)
-                                         if self.step_kind != "adaptive_coded"
-                                         else None)})
                 else:
                     times = sample_round_times_traced(
                         self.nodes, np.asarray(self.loads, float), rng,
                         trace_block)
                     xs = (times, lrs,
                           trace_block.active.astype(np.float32))
-                    if obs_spans.enabled():
-                        self._attr_blocks.append({
-                            "times": times,
-                            "active": np.asarray(trace_block.active)})
             fault_xs, fault_rng_new = self._fault_rows(state, K)
             scan_fn, thetas, words = self._prepare_scan(
                 state.collect, xs + fault_xs, state.lr_scale, state.theta,
@@ -1343,6 +1257,13 @@ class Experiment:
             out = np.asarray(packed)
             (t_rounds_b, n_ret_b, n_masked_b, skipped_b,
              lr_scale) = _unpack_outputs(out, K)
+        if obs_spans.enabled():
+            # the delays as the program saw them (f32) and the deadline
+            # it played, so `times > deadline` is the program's decision
+            self._attr_blocks.append({
+                "times": np.asarray(xs[0], np.float32),
+                "active": xs[2] if self.channel is not None else None,
+                "deadline": t_rounds_b})
         lm_log = state.lm_log
         if self.lm is not None:
             ext = lm_round.unpack_extras(out[4 * K + 1:], K)
@@ -1438,13 +1359,9 @@ class Experiment:
                 self.nodes, **self.scheme_params_estimator_kwargs())
             seg = plan_segment(self, est, trace, 0, state.iterations,
                                self.scheme_obj.initial_controls(self), rng)
-            xs = (seg.times, lrs, seg.active)
-            if self.step_kind == "adaptive_coded":
-                consts = dict(consts)
-                consts["gmask_blocks"] = seg.gmask_blocks
-                xs = xs + (seg.t_star_r, seg.block_idx)
-            else:
-                xs = xs + (seg.n_wait_r,)
+            extra, more_consts = self.scheme_obj.segment_inputs(self, seg)
+            consts = dict(consts, **more_consts)
+            xs = (seg.times, lrs, seg.active) + extra
             # the record kept is the LAST realization's plan, matching the
             # pre-RunState engine's `last_schedule` semantics
             sched_new = _append_sched(_empty_sched(self.n), seg)
@@ -1590,25 +1507,21 @@ class Experiment:
 
     def _assemble_schedule(self, sched: dict) -> AdaptiveSchedule:
         """Rebuild the run's `AdaptiveSchedule` from the state's
-        serialized record (gmasks are re-derived from the per-block
-        loads — `gmask_for_loads` is a pure function of them)."""
+        serialized record; the scheme's own fields come from
+        `Scheme.schedule_fields` (a coded scheme's load masks are a pure
+        function of the per-block loads)."""
         estimates = [
             {"mu": sched["est_mu"][b], "tau": sched["est_tau"][b],
              "p": sched["est_p"][b], "avail": sched["est_avail"][b],
              "rounds_seen": int(sched["est_rounds_seen"][b])}
             for b in range(sched["loads_blocks"].shape[0])]
-        out = AdaptiveSchedule(
+        return AdaptiveSchedule(
             times=sched["times"], active=sched["active"],
             block_idx=sched["block_idx"],
-            loads_blocks=sched["loads_blocks"], estimates=estimates)
-        if self.step_kind == "adaptive_coded":
-            out.t_star = sched["t_star_r"]
-            out.gmask_blocks = jnp.stack(
-                [self.scheme_obj.gmask_for_loads(self, loads)
-                 for loads in sched["loads_blocks"]])
-        else:
-            out.n_wait = sched["n_wait_r"]
-        return out
+            loads_blocks=sched["loads_blocks"], estimates=estimates,
+            **self.scheme_obj.schedule_fields(
+                self, sched["t_star_r"], sched["n_wait_r"],
+                sched["loads_blocks"]))
 
     # ------------------------------------------------------------ telemetry
     def attribution(self, k: int = 3):
@@ -1621,9 +1534,8 @@ class Experiment:
         Raises `RuntimeError` when nothing was captured."""
         from repro.obs.attribution import attribution_from_blocks
         return attribution_from_blocks(
-            self._attr_blocks, self.step_kind, t_star=self.t_star,
-            t_ideal=self.t_ideal, n_wait=self.n_wait,
-            loads=self.loads, m=self.m, k=k)
+            self._attr_blocks, loads=self.loads, m=self.m,
+            coded=self.scheme_obj.coded, k=k)
 
     def _drive(self, state: RunState, checkpoint_dir: Optional[str],
                eval_fn=None, eval_every: int = 10,

@@ -1,13 +1,17 @@
 """Straggler-mitigation scheme registry (the paper's §V "Schemes").
 
 Each scheme is a registered object that owns its deployment setup — load
-allocation, parity construction, privacy accounting — and its contributions
-to the compiled step (`fed_runtime.build_step` consts / gradient tensors)
-behind one common interface.  The runtime (`repro.core.fed_runtime`), the
-compiled sweep (`repro.launch.sweep`), and the benchmark grid
-(`repro.launch.bench`) all enumerate this registry, so registering a new
-scheme makes it runnable via ``repro.api.build_experiment`` and puts it in
-``BENCH_fed_training.json`` automatically.
+allocation, parity construction, privacy accounting — its contributions
+to the compiled step's consts and gradient tensors, and its per-round
+rule: the round's deadline, who counts as returned, and the gradient
+denominator (`Scheme.round_rule`).  `fed_runtime.build_step` calls the
+rule and keeps only what every scheme shares (fault rows, the non-finite
+guard, stale replay, the update and the divergence guard).  The runtime
+(`repro.core.fed_runtime`), the compiled sweep (`repro.launch.sweep`),
+and the benchmark grid (`repro.launch.bench`) all enumerate this
+registry, so registering a scheme makes it runnable via
+``repro.api.build_experiment`` and puts it in ``BENCH_fed_training.json``
+with no edit elsewhere.
 
 Built-in schemes:
 
@@ -25,7 +29,7 @@ Built-in schemes:
                    fraction comes from ``ExperimentSpec.scheme_params``
                    ("u_fraction", default 0.5).
 
-Registering your own::
+Registering your own — a new redundancy rule over the coded round::
 
     from repro.core import schemes
 
@@ -35,6 +39,27 @@ Registering your own::
             return 7   # any redundancy rule
 
     schemes.register(MyScheme())
+
+or a round with its own deadline, here the median of the clients
+present::
+
+    import jax.numpy as jnp
+
+    class MedianWait(schemes.Scheme):
+        name = "median_wait"
+
+        def round_rule(self, st, consts, t_row, ctl):
+            active = ctl[0] if ctl else jnp.ones_like(t_row)
+            n_act = jnp.sum(active).astype(jnp.int32)
+            srt = jnp.sort(jnp.where(active > 0, t_row, jnp.inf))
+            k = jnp.clip((n_act + 1) // 2, 1, st["n"])
+            t_round = jnp.where(n_act > 0, jnp.take(srt, k - 1), 0.0)
+            ret = (t_row <= t_round).astype(t_row.dtype) * active
+            n_ret = jnp.sum(ret).astype(jnp.int32)
+            denom = jnp.maximum(n_ret, 1).astype(jnp.float32) * st["l"]
+            return t_round, ret, n_ret, denom, consts["gmask"]
+
+    schemes.register(MedianWait())
 """
 from __future__ import annotations
 
@@ -50,21 +75,42 @@ from repro.obs import spans as obs_spans
 class Scheme:
     """Base scheme: full per-client loads, no parity, no deadline consts.
 
-    Subclasses set ``name`` (registry key) and ``step_kind`` (the static
-    branch `fed_runtime.build_step` compiles: one of "naive", "greedy",
-    "coded", "ideal", "adaptive_coded", "adaptive_greedy").  ``coded``
-    marks schemes that allocate loads and build a parity set (t_star /
-    loads / parity / privacy budget).  ``grid`` marks schemes that belong
-    to the default profile-grid sweep/benchmark
-    (`repro.launch.sweep.run_sweep` / `repro.launch.bench`); adaptive
-    schemes opt out — they need a channel trace and a per-run control
-    schedule, and are benched by the drift-scenario runner
-    (`repro.launch.scenarios`) instead.
+    Subclasses set ``name`` (registry key) and define `round_rule`.
+    ``step_kind`` is a label naming the round a scheme plays, read only
+    where a caller keeps its own copy of a rule: the per-client legacy
+    oracle, the hierarchical tier and the model round, which refuse the
+    kinds they do not play.  ``coded`` marks schemes that allocate loads
+    and build a parity set (t_star / loads / parity / privacy budget).
+    ``adaptive`` marks schemes that re-plan their controls between
+    blocks (`replan`) and feed them to the step as per-round scan inputs
+    (`segment_inputs`).  ``grid`` marks schemes that belong to the
+    default profile-grid sweep/benchmark (`repro.launch.sweep.run_sweep`
+    / `repro.launch.bench`); adaptive schemes opt out — they need a
+    channel trace and a per-run control schedule, and are benched by the
+    drift-scenario runner (`repro.launch.scenarios`) instead.
     """
     name: str = ""
     step_kind: str = ""
     coded: bool = False
+    adaptive: bool = False
     grid: bool = True
+
+    def round_rule(self, st, consts, t_row, ctl):
+        """One round's outcome inside the compiled step (traced).
+
+        `st` is the step's static dict (``n``, ``n_wait``, ``m``, ``l``,
+        ...; `fed_runtime.Experiment.step_static`), `consts` the step's
+        consts, `t_row` the round's (n,) f32 client delays.  `ctl` holds
+        the round's control inputs after the learning rate: ``()`` on
+        the stationary path, ``(active,)`` under a channel trace, and
+        ``(active, *segment_inputs)`` for an adaptive scheme.
+
+        Returns ``(t_round, ret_real, n_ret, denom, gmask)``: the round's
+        clock, the (n,) returned weights of the real clients, the count
+        the run reports as returned, the gradient-sum denominator, and
+        the gradient mask of this round.
+        """
+        raise NotImplementedError
 
     def setup(self, exp) -> None:
         """Host-side deployment setup; mutates the Experiment in place."""
@@ -102,6 +148,19 @@ class Scheme:
         greedy family); non-adaptive schemes never re-plan."""
         raise NotImplementedError(f"{self.name!r} is not adaptive")
 
+    def segment_inputs(self, exp, seg) -> "tuple[tuple, dict]":
+        """Adaptive-family hook: a planned segment's
+        (`repro.net.estimator.SegmentPlan`) per-round scan inputs, in the
+        order `round_rule` reads them after ``active``, and the consts
+        they index."""
+        raise NotImplementedError(f"{self.name!r} is not adaptive")
+
+    def schedule_fields(self, exp, t_star_r, n_wait_r, loads_blocks) -> dict:
+        """Adaptive-family hook: this scheme's fields of a run's
+        `repro.net.estimator.AdaptiveSchedule`, from the planned
+        per-round deadlines and wait counts and the per-block loads."""
+        raise NotImplementedError(f"{self.name!r} is not adaptive")
+
     def initial_controls(self, exp) -> dict:
         """The scheme's control-plane contribution to a fresh `RunState`:
         the values in effect at round 0, updated by `replan` thereafter
@@ -115,14 +174,55 @@ class Scheme:
         return f"<Scheme {self.name!r} step_kind={self.step_kind!r}>"
 
 
+def _kth_present(t_row, active, k, n):
+    """The deadline at the k-th fastest of the clients present (k clamped
+    to [1, present]; 0.0 with nobody present) and who made it."""
+    srt = jnp.sort(jnp.where(active > 0, t_row, jnp.inf))
+    n_act = jnp.sum(active).astype(jnp.int32)
+    k_eff = jnp.clip(jnp.minimum(k, n_act), 1, n)
+    t_round = jnp.where(n_act > 0, jnp.take(srt, k_eff - 1), 0.0)
+    return t_round, (t_row <= t_round).astype(t_row.dtype) * active
+
+
+def _average_over_returned(ret_real, l):
+    """n_ret and the denominator of a round that averages over the
+    returned clients' full loads."""
+    n_ret = jnp.sum(ret_real).astype(jnp.int32)
+    return n_ret, jnp.maximum(n_ret, 1).astype(jnp.float32) * l
+
+
 class NaiveScheme(Scheme):
+    """Waits for every client present: the round lasts the slowest."""
     name = "naive"
     step_kind = "naive"
 
+    def round_rule(self, st, consts, t_row, ctl):
+        if ctl:
+            active, = ctl
+            ret_real = active
+            n_ret = jnp.sum(active).astype(jnp.int32)
+            t_round = jnp.max(jnp.where(active > 0, t_row, 0.0))
+        else:
+            n_ret = jnp.int32(st["n"])
+            t_round = jnp.max(t_row)
+            ret_real = jnp.ones_like(t_row)
+        return t_round, ret_real, n_ret, st["m"], consts["gmask"]
+
 
 class GreedyScheme(Scheme):
+    """Waits for the fastest n_wait clients (of those present)."""
     name = "greedy"
     step_kind = "greedy"
+
+    def round_rule(self, st, consts, t_row, ctl):
+        if ctl:
+            t_round, ret_real = _kth_present(
+                t_row, ctl[0], jnp.int32(st["n_wait"]), st["n"])
+        else:
+            t_round = jnp.sort(t_row)[st["n_wait"] - 1]
+            ret_real = (t_row <= t_round).astype(t_row.dtype)
+        n_ret, denom = _average_over_returned(ret_real, st["l"])
+        return t_round, ret_real, n_ret, denom, consts["gmask"]
 
 
 class IdealScheme(Scheme):
@@ -142,6 +242,12 @@ class IdealScheme(Scheme):
     def extra_consts(self, exp) -> dict:
         return {"t_ideal": jnp.float32(exp.t_ideal)}
 
+    def round_rule(self, st, consts, t_row, ctl):
+        # every client present returns; the sampled t_row is ignored
+        ret_real = ctl[0] if ctl else jnp.ones_like(t_row)
+        n_ret = jnp.sum(ret_real).astype(jnp.int32)
+        return consts["t_ideal"], ret_real, n_ret, st["m"], consts["gmask"]
+
 
 class CodedScheme(Scheme):
     """CodedFedL (paper §III): optimized loads + global parity set.
@@ -149,8 +255,8 @@ class CodedScheme(Scheme):
     The parity set is also what makes the coded family *robust*: the
     MDS-style global parity gradient stands in for whatever client mass
     is missing from a round, whether that mass was lost to stragglers
-    (the paper's case) or masked out by the runtime's non-finite guard
-    (`fed_runtime.build_step` with fault injection, `repro.faults`).  A
+    (the paper's case) or masked out by the step's non-finite guard
+    (`fed_runtime._guard_and_sum` with fault injection, `repro.faults`).  A
     naive average has no such stand-in — masked returns simply shrink
     its effective batch, which is the coded-degrades-gracefully /
     naive-pays contrast the resilience benchmark records
@@ -159,6 +265,19 @@ class CodedScheme(Scheme):
     name = "coded"
     step_kind = "coded"
     coded = True
+
+    def round_rule(self, st, consts, t_row, ctl):
+        # the deadline is t*; a client returns by it if it holds a load
+        # (consts["active"]), while n_ret counts every on-time client
+        t_star = consts["t_star"]
+        by_deadline = (t_row <= t_star).astype(t_row.dtype)
+        ret_real = by_deadline * consts["active"]
+        if ctl:
+            active, = ctl
+            by_deadline = by_deadline * active
+            ret_real = ret_real * active
+        n_ret = jnp.sum(by_deadline).astype(jnp.int32)
+        return t_star, ret_real, n_ret, st["m"], consts["gmask"]
 
     # ------------------------------------------------------------ redundancy
     def u_budget(self, exp) -> int:
@@ -385,7 +504,25 @@ class AdaptiveCodedScheme(CodedScheme):
     """
     name = "adaptive_coded"
     step_kind = "adaptive_coded"
+    adaptive = True
     grid = False
+
+    def round_rule(self, st, consts, t_row, ctl):
+        # the block's re-planned deadline, and the index of its
+        # re-allocated fused load mask
+        active, t_star_r, block = ctl
+        ret_real = (t_row <= t_star_r).astype(t_row.dtype) * active
+        n_ret = jnp.sum(ret_real).astype(jnp.int32)
+        gmask = consts["gmask_blocks"][block]
+        return t_star_r, ret_real, n_ret, st["m"], gmask
+
+    def segment_inputs(self, exp, seg):
+        return ((seg.t_star_r, seg.block_idx),
+                {"gmask_blocks": self._gmask_blocks(exp, seg.loads_blocks)})
+
+    def schedule_fields(self, exp, t_star_r, n_wait_r, loads_blocks):
+        return {"t_star": t_star_r,
+                "gmask_blocks": self._gmask_blocks(exp, loads_blocks)}
 
     def setup(self, exp) -> None:
         if not exp.fused_coded:
@@ -437,6 +574,11 @@ class AdaptiveCodedScheme(CodedScheme):
         mask[exp.n, :exp.u] = 1.0 / exp.u
         return jnp.asarray(mask)
 
+    def _gmask_blocks(self, exp, loads_blocks) -> jnp.ndarray:
+        """(B, n+1, L) fused masks, one per block's load vector."""
+        return jnp.stack([self.gmask_for_loads(exp, loads)
+                          for loads in loads_blocks])
+
     # ----------------------------------------------------------------- replan
     def replan(self, exp, estimator) -> dict:
         from repro.core import load_allocation
@@ -472,7 +614,20 @@ class AdaptiveGreedyScheme(GreedyScheme):
     """
     name = "adaptive_greedy"
     step_kind = "adaptive_greedy"
+    adaptive = True
     grid = False
+
+    def round_rule(self, st, consts, t_row, ctl):
+        active, n_wait_r = ctl          # the block's re-tuned wait count
+        t_round, ret_real = _kth_present(t_row, active, n_wait_r, st["n"])
+        n_ret, denom = _average_over_returned(ret_real, st["l"])
+        return t_round, ret_real, n_ret, denom, consts["gmask"]
+
+    def segment_inputs(self, exp, seg):
+        return (seg.n_wait_r,), {}
+
+    def schedule_fields(self, exp, t_star_r, n_wait_r, loads_blocks):
+        return {"n_wait": n_wait_r}
 
     def replan(self, exp, estimator) -> dict:
         est_nodes = estimator.estimated_nodes()
@@ -504,11 +659,8 @@ def register(scheme: Scheme, *, overwrite: bool = False) -> Scheme:
     """
     if not scheme.name:
         raise ValueError(f"{scheme!r} has no name")
-    if scheme.step_kind not in ("naive", "greedy", "coded", "ideal",
-                                "adaptive_coded", "adaptive_greedy"):
-        raise ValueError(
-            f"scheme {scheme.name!r} has unknown step_kind "
-            f"{scheme.step_kind!r}")
+    if type(scheme).round_rule is Scheme.round_rule:
+        raise ValueError(f"scheme {scheme.name!r} defines no round_rule")
     if scheme.name in _REGISTRY and not overwrite:
         raise ValueError(f"scheme {scheme.name!r} already registered "
                          "(pass overwrite=True to replace)")

@@ -5,8 +5,9 @@ work (stragglers, erasures — `repro.net`).  This subsystem injects the
 *wrong*-work failure modes a real MEC deployment adds on top — non-finite
 client gradient returns, stale-update replay, corrupted parity uploads,
 checkpoint truncation/bit-flips, and service block crashes — so the
-runtime's graceful-degradation machinery (`fed_runtime.build_step`'s
-non-finite guard, `checkpoint.io`'s digest verification,
+runtime's graceful-degradation machinery (the compiled step's
+non-finite guard, `fed_runtime._guard_and_sum`, which every scheme's
+round shares; `checkpoint.io`'s digest verification;
 `launch.service`'s retry/backoff) can be exercised deterministically.
 
 `FaultProfile` declares a fault mix the way `repro.net.channel

@@ -233,7 +233,8 @@ class SegmentPlan:
 
     Produced by `plan_segment` for global rounds ``[r0, r1)``; the
     per-round arrays are segment-local, ``block_idx`` indexes into this
-    segment's ``loads_blocks``/``gmask_blocks``, and ``controls`` carries
+    segment's ``loads_blocks`` (the scheme turns them into its scan
+    inputs, `Scheme.segment_inputs`), and ``controls`` carries
     the live control values forward so the next segment continues exactly
     where this one stopped.
     """
@@ -243,7 +244,6 @@ class SegmentPlan:
     t_star_r: np.ndarray                    # (r1-r0,) float32
     n_wait_r: np.ndarray                    # (r1-r0,) int32
     loads_blocks: np.ndarray                # (B_seg, n) float64
-    gmask_blocks: Optional[object]          # (B_seg, rows, L) jnp (coded)
     estimates: list                         # one snapshot per sub-block
     controls: dict                          # {"loads","t_star","n_wait"}
 
@@ -270,8 +270,6 @@ def plan_segment(exp, estimator: OnlineChannelEstimator,
     if trace_seg.rounds < R_seg:
         raise ValueError(f"trace segment covers {trace_seg.rounds} rounds, "
                          f"need {R_seg}")
-    coded = exp.step_kind == "adaptive_coded"
-
     loads = np.asarray(controls["loads"], np.float64).copy()
     t_star = controls.get("t_star")
     n_wait = controls.get("n_wait")
@@ -281,7 +279,7 @@ def plan_segment(exp, estimator: OnlineChannelEstimator,
     block_idx = np.zeros(R_seg, np.int32)
     t_star_r = np.zeros(R_seg, np.float32)
     n_wait_r = np.zeros(R_seg, np.int32)
-    loads_list, gmasks, estimates = [], [], []
+    loads_list, estimates = [], []
 
     b_local = -1
     r = int(r0)
@@ -293,8 +291,6 @@ def plan_segment(exp, estimator: OnlineChannelEstimator,
             n_wait = plan_b.get("n_wait", n_wait)
         b_local += 1
         r_end = min(int(r1), (r // K + 1) * K)
-        if coded:
-            gmasks.append(exp.scheme_obj.gmask_for_loads(exp, loads))
         # block delays consume the run's RNG sequentially, exactly like
         # the static engine's one-shot pre-sampling
         obs = sample_round_observations(
@@ -311,15 +307,10 @@ def plan_segment(exp, estimator: OnlineChannelEstimator,
         estimates.append(estimator.snapshot())
         r = r_end
 
-    gmask_blocks = None
-    if coded:
-        import jax.numpy as jnp
-        gmask_blocks = jnp.stack(gmasks)
     return SegmentPlan(
         times=times, active=active, block_idx=block_idx,
         t_star_r=t_star_r, n_wait_r=n_wait_r,
-        loads_blocks=np.stack(loads_list), gmask_blocks=gmask_blocks,
-        estimates=estimates,
+        loads_blocks=np.stack(loads_list), estimates=estimates,
         controls={"loads": loads.copy(), "t_star": t_star,
                   "n_wait": n_wait})
 
@@ -348,12 +339,8 @@ class AdaptiveController:
                              f"need {R}")
         seg = plan_segment(exp, self.estimator, self.trace, 0, R,
                            exp.scheme_obj.initial_controls(exp), exp.rng)
-        sched = AdaptiveSchedule(
+        return AdaptiveSchedule(
             times=seg.times, active=seg.active, block_idx=seg.block_idx,
-            loads_blocks=seg.loads_blocks, estimates=seg.estimates)
-        if exp.step_kind == "adaptive_coded":
-            sched.t_star = seg.t_star_r
-            sched.gmask_blocks = seg.gmask_blocks
-        else:
-            sched.n_wait = seg.n_wait_r
-        return sched
+            loads_blocks=seg.loads_blocks, estimates=seg.estimates,
+            **exp.scheme_obj.schedule_fields(
+                exp, seg.t_star_r, seg.n_wait_r, seg.loads_blocks))

@@ -8,7 +8,6 @@ from repro.obs.attribution import (
     Attribution,
     attribution_from_blocks,
     compute_attribution,
-    round_deadlines,
 )
 from repro.obs.events import (
     EVENTS_NAME,
@@ -36,5 +35,4 @@ __all__ = [
     "Attribution",
     "attribution_from_blocks",
     "compute_attribution",
-    "round_deadlines",
 ]

@@ -8,8 +8,9 @@ a numpy reference per block, no RNG touched, no extra draws — and this
 module turns them into the paper's delay analysis, per client:
 
   * **deadline-miss rate** — fraction of rounds a client exceeded the
-    round deadline (t* for the coded family, the n_wait-th order
-    statistic for the greedy family, the round max for naive);
+    round deadline: the deadline the compiled round played
+    (`Scheme.round_rule`, fetched with the block's outputs) in the flat
+    runtime, each shard's t* in the hierarchical tier;
   * **slowest-k contributions** — how often the client was among the k
     slowest present that round (who *drives* the tail, not just who
     misses);
@@ -27,7 +28,7 @@ import dataclasses
 
 import numpy as np
 
-__all__ = ["Attribution", "compute_attribution", "round_deadlines"]
+__all__ = ["Attribution", "attribution_from_blocks", "compute_attribution"]
 
 
 @dataclasses.dataclass
@@ -40,6 +41,8 @@ class Attribution:
     active_rounds: np.ndarray    # (n,) rounds the client was present
     slowest_k_counts: np.ndarray  # (n,) rounds among the k slowest present
     comp_share: np.ndarray       # (rounds,) coded-compensation data share
+    deadline: np.ndarray         # (rounds,) the round's deadline
+    on_time: np.ndarray          # (rounds,) clients present and on time
 
     def top_stragglers(self, count: int = 5) -> "list[tuple[int, float]]":
         """[(client, miss_rate)] sorted worst-first, ties by client id."""
@@ -60,39 +63,6 @@ class Attribution:
             if len(self.comp_share) else 0.0,
             "top_stragglers": [[j, r] for j, r in self.top_stragglers()],
         }
-
-
-def round_deadlines(step_kind: str, times: np.ndarray, active: np.ndarray,
-                    *, t_star=None, t_ideal=None, n_wait=None,
-                    t_star_r=None, n_wait_r=None) -> np.ndarray:
-    """(T,) per-round deadline implied by the scheme's step kind.
-
-    Mirrors `fed_runtime.build_step`'s host-visible deadline logic:
-    coded uses the (possibly re-planned) t*, greedy the n_wait-th order
-    statistic among clients present, naive the max over clients present,
-    ideal its deterministic round clock.
-    """
-    T, n = times.shape
-    if step_kind == "coded":
-        if t_star_r is not None:
-            return np.asarray(t_star_r, np.float64)
-        return np.full(T, float(t_star), np.float64)
-    if step_kind == "adaptive_coded":
-        return np.asarray(t_star_r, np.float64)
-    if step_kind == "ideal":
-        return np.full(T, float(t_ideal), np.float64)
-    if step_kind == "naive":
-        masked = np.where(active > 0, times, 0.0)
-        return masked.max(axis=1)
-    if step_kind in ("greedy", "adaptive_greedy"):
-        waits = (np.asarray(n_wait_r, np.int64) if n_wait_r is not None
-                 else np.full(T, int(n_wait), np.int64))
-        srt = np.sort(np.where(active > 0, times, np.inf), axis=1)
-        n_act = (active > 0).sum(axis=1)
-        k_eff = np.clip(np.minimum(waits, n_act), 1, n)
-        dl = srt[np.arange(T), k_eff - 1]
-        return np.where(n_act > 0, dl, 0.0)
-    raise ValueError(f"unknown step kind {step_kind!r}")
 
 
 def compute_attribution(times: np.ndarray, active, deadline: np.ndarray,
@@ -124,8 +94,8 @@ def compute_attribution(times: np.ndarray, active, deadline: np.ndarray,
     tail_live = np.take_along_axis(active, tail, axis=1)
     slowest = np.zeros(n, np.int64)
     np.add.at(slowest, tail[tail_live], 1)
+    ret = (~miss) & active
     if coded:
-        ret = (~miss) & active
         mass = (np.asarray(loads, np.float64)[None, :] * ret).sum(axis=1)
         comp_share = np.clip(1.0 - mass / float(m), 0.0, 1.0)
     else:
@@ -133,15 +103,15 @@ def compute_attribution(times: np.ndarray, active, deadline: np.ndarray,
     return Attribution(rounds=T, k=int(min(k, n)), miss_rate=miss_rate,
                        miss_counts=miss_counts,
                        active_rounds=active_rounds,
-                       slowest_k_counts=slowest, comp_share=comp_share)
+                       slowest_k_counts=slowest, comp_share=comp_share,
+                       deadline=deadline, on_time=ret.sum(axis=1))
 
 
-def attribution_from_blocks(blocks: "list[dict]", step_kind: str, *,
-                            t_star=None, t_ideal=None, n_wait=None,
-                            loads=None, m=None, k: int = 3) -> Attribution:
+def attribution_from_blocks(blocks: "list[dict]", *, loads=None, m=None,
+                            coded: bool = False, k: int = 3) -> Attribution:
     """Concatenate per-block captures (`fed_runtime._block_single`) and
     attribute.  Each block dict: ``times`` (K, n), optional ``active``
-    (K, n), optional per-round controls ``t_star_r`` / ``n_wait_r``."""
+    (K, n), and ``deadline`` (K,), the deadlines the program played."""
     if not blocks:
         raise RuntimeError(
             "no telemetry captured for this run: call "
@@ -152,15 +122,7 @@ def attribution_from_blocks(blocks: "list[dict]", step_kind: str, *,
         [np.asarray(b["active"], np.float64) if b.get("active") is not None
          else np.ones_like(np.asarray(b["times"], np.float64))
          for b in blocks])
-    has_tsr = any(b.get("t_star_r") is not None for b in blocks)
-    has_nwr = any(b.get("n_wait_r") is not None for b in blocks)
-    t_star_r = (np.concatenate([np.asarray(b["t_star_r"], np.float64)
-                                for b in blocks]) if has_tsr else None)
-    n_wait_r = (np.concatenate([np.asarray(b["n_wait_r"], np.int64)
-                                for b in blocks]) if has_nwr else None)
-    deadline = round_deadlines(step_kind, times, active, t_star=t_star,
-                               t_ideal=t_ideal, n_wait=n_wait,
-                               t_star_r=t_star_r, n_wait_r=n_wait_r)
-    return compute_attribution(
-        times, active, deadline, loads=loads, m=m,
-        coded=step_kind in ("coded", "adaptive_coded"), k=k)
+    deadline = np.concatenate([np.asarray(b["deadline"], np.float64)
+                               for b in blocks])
+    return compute_attribution(times, active, deadline, loads=loads, m=m,
+                               coded=coded, k=k)

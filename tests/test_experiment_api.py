@@ -10,6 +10,7 @@ partial-redundancy one) runs through `repro.api.build_experiment`.
 """
 import json
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -183,11 +184,74 @@ def test_register_rejects_duplicates_and_bad_kinds():
         schemes.register(Nameless())
 
     class BadKind(schemes.Scheme):
+        # a kind label alone is not a round: the scheme defines no rule
         name = "bad_kind"
         step_kind = "quantum"
 
-    with pytest.raises(ValueError, match="step_kind"):
+    with pytest.raises(ValueError, match="round_rule"):
         schemes.register(BadKind())
+    assert "bad_kind" not in schemes.registered_names()
+
+
+class _MedianWait(schemes.Scheme):
+    """A deadline no module outside this file knows: the median of the
+    clients present."""
+    name = "test_median_wait"
+
+    def round_rule(self, st, consts, t_row, ctl):
+        active = ctl[0] if ctl else jnp.ones_like(t_row)
+        n_act = jnp.sum(active).astype(jnp.int32)
+        srt = jnp.sort(jnp.where(active > 0, t_row, jnp.inf))
+        k = jnp.clip((n_act + 1) // 2, 1, st["n"])
+        t_round = jnp.where(n_act > 0, jnp.take(srt, k - 1), 0.0)
+        ret = (t_row <= t_round).astype(t_row.dtype) * active
+        n_ret = jnp.sum(ret).astype(jnp.int32)
+        denom = jnp.maximum(n_ret, 1).astype(jnp.float32) * st["l"]
+        return t_round, ret, n_ret, denom, consts["gmask"]
+
+
+@pytest.fixture
+def median_wait():
+    scheme = schemes.register(_MedianWait())
+    try:
+        yield scheme
+    finally:
+        schemes.unregister(scheme.name)
+
+
+def test_registered_round_rule_runs_on_every_path(median_wait):
+    """A scheme registered with a new round rule runs through
+    build_experiment, run, run_multi, run_sweep and attribution() with no
+    edit outside this file: half of the clients (rounded up) make each
+    round's deadline, on every path."""
+    from repro.launch.sweep import run_sweep
+    from repro.obs import spans as obs_spans
+
+    xs, ys = _data()
+    half = (xs.shape[0] + 1) // 2
+    spec = _spec(median_wait.name, checkpoint_every=3)
+    exp = api.build_experiment(spec, xs, ys)
+    res = exp.run(6)
+    assert [h.returned for h in res.history] == [half] * 6
+    assert np.isfinite(np.asarray(res.theta)).all()
+
+    multi = exp.run_multi(5, 3)
+    np.testing.assert_array_equal(multi.returned, np.full((3, 5), half))
+
+    sweep = run_sweep(xs, ys, profiles={"paper": {}, "slow": {"mac_decay": 0.7}},
+                      train_cfg=spec.train, iterations=4, realizations=2,
+                      schemes=(median_wait.name,), base_spec=spec)
+    for got in sweep.results[median_wait.name].values():
+        np.testing.assert_array_equal(got.returned, np.full((2, 4), half))
+
+    with obs_spans.collecting():
+        state = exp.init_state(6)
+        while not state.done:
+            state = exp.run_block(state)
+        attr = exp.attribution()
+    assert attr.deadline.tobytes() == state.t_rounds.tobytes()
+    np.testing.assert_array_equal(attr.on_time, np.full(6, half))
+    np.testing.assert_array_equal(state.n_ret, np.full(6, half))
 
 
 @pytest.mark.parametrize("scheme", ["coded", "naive", "greedy", "ideal",
@@ -305,12 +369,15 @@ def test_mesh_in_spec_shards_like_mesh_kwarg():
 
 
 def test_step_static_exposes_step_kind():
-    """Coded-family schemes compile the same step branch; the registry
-    decides, not string comparison on the scheme name."""
+    """Coded-family schemes play the same round rule; the registered
+    object decides, not string comparison on the scheme name."""
     xs, ys = _data()
     partial = api.build_experiment(_spec("partial_coded"), xs, ys)
     assert partial.step_kind == "coded"
-    assert partial.step_static()["scheme"] == "coded"
+    assert partial.step_static()["scheme"] is schemes.get_scheme(
+        "partial_coded")
+    assert (type(partial.step_static()["scheme"]).round_rule
+            is schemes.CodedScheme.round_rule)
     ideal = api.build_experiment(_spec("ideal"), xs, ys)
-    assert ideal.step_static()["scheme"] == "ideal"
+    assert ideal.step_static()["scheme"] is schemes.get_scheme("ideal")
     assert "t_ideal" in ideal.build_consts()

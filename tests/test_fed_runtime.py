@@ -138,14 +138,10 @@ def _reference_block(exp, state, K, eval_fn):
             est.load_state_dict(state.est)
             seg = plan_segment(exp, est, trace_block, r0, r0 + K,
                                state.controls, rng)
+            extra, more_consts = exp.scheme_obj.segment_inputs(exp, seg)
+            consts = dict(consts, **more_consts)
             xs = (jnp.asarray(seg.times, jnp.float32), jnp.asarray(lrs),
-                  jnp.asarray(seg.active))
-            if exp.step_kind == "adaptive_coded":
-                consts = dict(consts, gmask_blocks=seg.gmask_blocks)
-                xs += (jnp.asarray(seg.t_star_r, jnp.float32),
-                       jnp.asarray(seg.block_idx))
-            else:
-                xs += (jnp.asarray(seg.n_wait_r),)
+                  jnp.asarray(seg.active)) + tuple(map(jnp.asarray, extra))
         else:
             times = sample_round_times_traced(exp.nodes, loads, rng,
                                               trace_block)

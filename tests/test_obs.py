@@ -18,9 +18,9 @@ import pytest
 from repro import api
 from repro.config import ExperimentSpec, FLConfig, TrainConfig
 from repro.launch.report import REQUIRED_SPANS
+from repro.core import schemes
 from repro.obs import (RunJournal, attribution_from_blocks,
-                       histories_equal, history_from_journal, load_events,
-                       round_deadlines)
+                       histories_equal, history_from_journal, load_events)
 from repro.obs import spans as obs_spans
 from repro.obs.events import EVENTS_NAME
 
@@ -416,35 +416,36 @@ def test_attribution_bounds_and_report():
     assert 0.0 <= d["comp_share_mean"] <= 1.0
 
 
-def test_round_deadlines_per_step_kind():
-    rng = np.random.default_rng(0)
-    times = rng.uniform(1.0, 5.0, size=(4, 5))
-    active = np.ones((4, 5), dtype=bool)
-    active[2, :3] = False
-
-    coded = round_deadlines("coded", times, active, t_star=2.5)
-    np.testing.assert_array_equal(coded, np.full(4, 2.5))
-    per_round = np.array([1.0, 2.0, 3.0, 4.0])
-    np.testing.assert_array_equal(
-        round_deadlines("adaptive_coded", times, active,
-                        t_star_r=per_round), per_round)
-    naive = round_deadlines("naive", times, active)
-    np.testing.assert_array_equal(
-        naive, np.where(active, times, 0.0).max(axis=1))
-    greedy = round_deadlines("greedy", times, active, n_wait=3)
-    srt = np.sort(np.where(active, times, np.inf), axis=1)
-    # row 2 has only 2 active clients -> waits clamps to its live count
-    expect = np.array([srt[0, 2], srt[1, 2], srt[2, 1], srt[3, 2]])
-    np.testing.assert_array_equal(greedy, expect)
+@pytest.mark.parametrize("scheme", schemes.registered_names())
+def test_attribution_reads_the_deadlines_the_program_played(scheme):
+    """Per-round deadlines are the run's t_rounds bit for bit, and where
+    the deadline decides the returns, the clients present and on time
+    each round are the n_ret the program reported."""
+    xs, ys = _data()
+    over = (dict(channel_profile="drift_churn", adapt_every=2)
+            if schemes.get_scheme(scheme).adaptive else {})
+    exp = api.build_experiment(_spec(scheme, **over), xs, ys)
+    obs_spans.enable()
+    state = exp.init_state(10)
+    while not state.done:
+        state = exp.run_block(state)
+    attr = exp.attribution()
+    assert attr.deadline.dtype == state.t_rounds.dtype
+    assert attr.deadline.tobytes() == state.t_rounds.tobytes()
+    if scheme not in ("naive", "ideal"):
+        np.testing.assert_array_equal(attr.on_time, state.n_ret)
 
 
 def test_attribution_from_blocks_concatenates():
-    blocks = [{"times": np.full((3, 4), 1.0), "active": None},
-              {"times": np.full((2, 4), 9.0), "active": None}]
+    blocks = [{"times": np.full((3, 4), 1.0), "active": None,
+               "deadline": np.full(3, 2.0)},
+              {"times": np.full((2, 4), 9.0), "active": None,
+               "deadline": np.full(2, 8.0)}]
     attr = attribution_from_blocks(
-        blocks, "coded", t_star=2.0, t_ideal=1.0, n_wait=2,
-        loads=np.full(4, 0.5), m=2.0, k=1)
+        blocks, loads=np.full(4, 0.5), m=2.0, coded=True, k=1)
     assert attr.rounds == 5
+    np.testing.assert_array_equal(attr.deadline, [2.0] * 3 + [8.0] * 2)
+    np.testing.assert_array_equal(attr.on_time, [4] * 3 + [0] * 2)
     # rounds in block 2 all miss the coded deadline
     np.testing.assert_array_equal(attr.miss_counts, np.full(4, 2))
     np.testing.assert_allclose(attr.miss_rate, 0.4)
